@@ -1,7 +1,7 @@
 """TPU-native Hoplite collectives: HLO link-byte + step-count comparison.
 
-The container has no TPU, so this benchmark compares the *compiled
-schedules* (the dry-run methodology): for a gradient-sized tensor on an
+A CPU-only tool (it forces 8 host devices before jax is imported): it
+compares the *compiled schedules* (the dry-run methodology): for a gradient-sized tensor on an
 8-way axis, lower each allreduce implementation and report
 
   * collective-permute / all-reduce link bytes per device (HLO walk),
@@ -31,12 +31,13 @@ from benchmarks.common import MB, emit
 from repro.core import collectives as C
 from repro.core.planner import DCN_LINK, ICI_LINK
 from repro.launch import hlo_cost
+from repro.launch.mesh import auto_mesh
 
 SIZE_ELEMS = 8 * MB // 4  # a 8 MB f32 gradient bucket
 
 
 def lower_and_walk(fn, n=8):
-    mesh = jax.make_mesh((n,), ("x",))
+    mesh = auto_mesh((n,), ("x",))
     x = jax.ShapeDtypeStruct((n, SIZE_ELEMS), jnp.float32)
     g = jax.shard_map(fn, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
     with jax.set_mesh(mesh):

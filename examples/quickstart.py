@@ -6,7 +6,8 @@
 2. The same schedules as TPU collectives (8 host devices): the paper's
    chain allreduce vs XLA's psum, bit-identical results.
 
-Run:  PYTHONPATH=src python examples/quickstart.py
+Run (a CPU-only tool: it forces 8 host devices before jax is imported):
+    JAX_PLATFORMS=cpu PYTHONPATH=src python examples/quickstart.py
 """
 
 import os
@@ -57,9 +58,10 @@ def tpu_collectives_demo():
     from jax.sharding import PartitionSpec as P
 
     from repro.core import collectives as C
+    from repro.launch.mesh import auto_mesh
 
     print("== Hoplite chain schedules as TPU collectives (8 devices) ==")
-    mesh = jax.make_mesh((8,), ("x",))
+    mesh = auto_mesh((8,), ("x",))
     x = np.random.RandomState(1).rand(8, 4096).astype(np.float32)
 
     def run(fn):

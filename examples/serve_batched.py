@@ -5,7 +5,8 @@ prefill/decode-vs-full-forward consistency check (the strongest
 correctness property a cache path can satisfy), plus the slot-based
 continuous batching loop over a queue of requests.
 
-Run:  PYTHONPATH=src python examples/serve_batched.py
+Run (a CPU-only tool: it forces 8 host devices before jax is imported):
+    JAX_PLATFORMS=cpu PYTHONPATH=src python examples/serve_batched.py
 """
 
 import os
@@ -21,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ARCHS, reduced_config
-from repro.launch.mesh import make_debug_mesh
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as T
 from repro.models.common import init_params
 from repro.serving.engine import BatchingLoop, Engine, Request, ServeOptions
@@ -30,7 +31,7 @@ from repro.train import step as TS
 
 def main():
     cfg = reduced_config(ARCHS["gemma3-27b"])  # local:global pattern + tail
-    mesh = make_debug_mesh()
+    mesh = make_mesh(model=2)  # (data=4, model=2) on the 8 host devices
     with jax.set_mesh(mesh):
         shardings = TS.state_shardings(cfg, mesh)["params"]
         params = init_params(T.model_skel(cfg), jax.random.PRNGKey(0))

@@ -5,7 +5,8 @@ synthetic pipeline with the production train step (FSDP x TP mesh,
 microbatched grad accumulation, remat, async checkpointing), including a
 mid-run simulated crash + restart from checkpoint.
 
-Run:  PYTHONPATH=src python examples/train_lm.py [--steps 300]
+Run (a CPU-only tool: it forces 4 host devices before jax is imported):
+    JAX_PLATFORMS=cpu PYTHONPATH=src python examples/train_lm.py [--steps 300]
 """
 
 import argparse
@@ -25,7 +26,7 @@ from repro.checkpoint.checkpoint import Checkpointer
 from repro.configs import get_config
 from repro.configs.base import LayerSpec, ShapeSpec
 from repro.data import pipeline
-from repro.launch.mesh import make_debug_mesh  # (2,2) on 4 host devices
+from repro.launch.mesh import make_mesh
 from repro.sharding import partitioning
 from repro.train import step as TS
 
@@ -62,7 +63,7 @@ def main():
 
     print(f"model: {cfg.name}, {param_elems(model_skel(cfg))/1e6:.1f}M params")
     shape = ShapeSpec("lm100m", seq_len=64, global_batch=4, kind="train")
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_mesh(model=2)  # (data=2, model=2) on the 4 host devices
     opts = TS.TrainOptions(
         num_microbatches=1,
         adamw=dataclasses.replace(TS.TrainOptions().adamw, lr=1e-3, warmup_steps=30,

@@ -1,12 +1,6 @@
 """Hoplite reproduction package.
 
-Importing :mod:`repro` installs jax forward-compat aliases (see
-:mod:`repro._compat`) when jax is available; the pure-python core
-(``repro.core``, ``repro.runtime``, ``repro.serve``) stays importable
-without jax.
+The pure-python core (``repro.core``, ``repro.runtime``, ``repro.serve``)
+imports without jax; the device path (``collectives``, ``train``,
+``launch``) targets the installed jax (0.9) directly.
 """
-
-try:
-    from repro import _compat  # noqa: F401
-except ImportError:  # pure-numpy environments: core/ runtime/ serve/ only
-    pass

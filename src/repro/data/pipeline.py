@@ -78,7 +78,11 @@ def device_batch(
 
 
 class Prefetcher:
-    """Background-thread batch prefetch (overlap host gen with device step)."""
+    """Background-thread batch prefetch (overlap host gen with device step).
+
+    A producer failure (e.g. a batch that cannot be placed on the mesh)
+    ends the thread and is re-raised by every later ``next()``, so the
+    consumer fails instead of waiting forever."""
 
     def __init__(self, cfg, shape, mesh, specs, start_step: int = 0, seed: int = 0, depth: int = 2):
         self.cfg, self.shape, self.mesh, self.specs, self.seed = cfg, shape, mesh, specs, seed
@@ -90,16 +94,23 @@ class Prefetcher:
 
     def _run(self):
         step = self.step
-        while not self.stop.is_set():
-            batch = device_batch(self.cfg, self.shape, step, self.mesh, self.specs, self.seed)
-            self.q.put((step, batch))
-            step += 1
+        try:
+            while not self.stop.is_set():
+                batch = device_batch(self.cfg, self.shape, step, self.mesh, self.specs, self.seed)
+                self.q.put((step, batch))
+                step += 1
+        except Exception as e:  # noqa: BLE001 -- re-raised by the consumer
+            self.q.put(e)
 
     def __iter__(self) -> Iterator:
         return self
 
     def __next__(self):
-        return self.q.get()
+        item = self.q.get()
+        if isinstance(item, Exception):
+            self.q.put(item)  # the producer is gone: later calls raise too
+            raise item
+        return item
 
     def close(self):
         self.stop.set()

@@ -7,8 +7,9 @@ object"); on TPU this is the per-chunk body of core/collectives.py's
 chain schedules.  ``dequant_add`` is the compressed-chain variant
 (int8 payload + per-block scales, matching optim/compression.py).
 
-BlockSpec tiling: 1-D tiles of ``block`` elements staged through VMEM;
-accumulation in f32 regardless of storage dtype.
+BlockSpec tiling: ``chunk_reduce`` streams 1-D tiles of ``block``
+elements through VMEM; ``dequant_add`` streams (rows, qblock) tiles.
+Accumulation is in f32 regardless of storage dtype.
 """
 
 from __future__ import annotations
@@ -58,12 +59,10 @@ def chunk_reduce(
     return out[:n].reshape(dst.shape)
 
 
-def _dequant_add_kernel(dst_ref, q_ref, scale_ref, o_ref, *, qblock: int):
-    d = dst_ref[...].astype(jnp.float32)  # (block,)
-    q = q_ref[...].astype(jnp.float32)  # (block,)
-    s = scale_ref[...]  # (block // qblock,)
-    deq = (q.reshape(-1, qblock) * s[:, None]).reshape(-1)
-    o_ref[...] = (d + deq).astype(o_ref.dtype)
+def _dequant_add_kernel(dst_ref, q_ref, scale_ref, o_ref):
+    d = dst_ref[...].astype(jnp.float32)  # (rows, qblock)
+    q = q_ref[...].astype(jnp.float32)  # (rows, qblock)
+    o_ref[...] = (d + q * scale_ref[...]).astype(o_ref.dtype)  # scale (rows, 1)
 
 
 def dequant_add(
@@ -71,32 +70,35 @@ def dequant_add(
     q: jax.Array,  # int8, padded to multiple of qblock
     scale: jax.Array,  # f32 per-qblock scales
     qblock: int = 256,
-    block: int = 16 * 1024,
+    block_rows: int = 64,
     interpret: bool = False,
 ) -> jax.Array:
-    """dst + dequant(q, scale): the compressed chain-hop accumulate."""
+    """dst + dequant(q, scale): the compressed chain-hop accumulate.
+
+    Laid out 2-D as (quant blocks, qblock) with the scales as a
+    (quant blocks, 1) column, so every tile is lane-aligned on TPU (a 1-D
+    scale tile of block // qblock elements is not)."""
     flat_d = dst.reshape(-1)
     n = flat_d.shape[0]
     npad = q.size  # already padded to qblock multiple
     assert npad % qblock == 0 and npad >= n
-    block = min(block, npad)
-    block = max(qblock, block - block % qblock)
-    pad = (-npad) % block
-    qf = q.reshape(-1)
-    df = jnp.pad(flat_d, (0, npad - n + pad))
-    qf = jnp.pad(qf, (0, pad))
-    sf = jnp.pad(scale, (0, (df.shape[0] // qblock) - scale.shape[0]))
-    grid = (df.shape[0] // block,)
+    rows = npad // qblock
+    block_rows = min(block_rows, rows)
+    pad_rows = (-rows) % block_rows
+    total = rows + pad_rows
+    df = jnp.pad(flat_d, (0, total * qblock - n)).reshape(total, qblock)
+    qf = jnp.pad(q.reshape(rows, qblock), ((0, pad_rows), (0, 0)))
+    sf = jnp.pad(scale.reshape(rows, 1), ((0, pad_rows), (0, 0)))
     out = pl.pallas_call(
-        functools.partial(_dequant_add_kernel, qblock=qblock),
-        grid=grid,
+        _dequant_add_kernel,
+        grid=(total // block_rows,),
         in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block // qblock,), lambda i: (i,)),
+            pl.BlockSpec((block_rows, qblock), lambda i: (i, 0)),
+            pl.BlockSpec((block_rows, qblock), lambda i: (i, 0)),
+            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((block,), lambda i: (i,)),
+        out_specs=pl.BlockSpec((block_rows, qblock), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(df.shape, dst.dtype),
         interpret=interpret,
     )(df, qf, sf)
-    return out[:n].reshape(dst.shape)
+    return out.reshape(-1)[:n].reshape(dst.shape)

@@ -23,6 +23,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
@@ -136,19 +137,11 @@ def flash_attention_fwd(
         out_specs=pl.BlockSpec((1, block_q, D), lambda bh, qi, ki: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, D), q.dtype),
         scratch_shapes=[
-            _vmem((block_q,), jnp.float32),
-            _vmem((block_q,), jnp.float32),
-            _vmem((block_q, D), jnp.float32),
+            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q,), jnp.float32),
+            pltpu.VMEM((block_q, D), jnp.float32),
         ],
         interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(B, H, Sq, D)
 
-
-def _vmem(shape, dtype):
-    try:
-        from jax.experimental.pallas import tpu as pltpu
-
-        return pltpu.VMEM(shape, dtype)
-    except Exception:  # pragma: no cover - CPU interpret fallback
-        return pl.MemorySpace.ANY(shape, dtype)

@@ -2,9 +2,10 @@
 
 ``flash_attention`` carries a custom_vjp whose backward is the blockwise
 jnp formulation from models/attention.py -- the forward runs the Pallas
-kernel on TPU (interpret mode on CPU), the backward the XLA-fused ref.
-All wrappers auto-select interpret mode off-TPU so the same call sites
-work in tests, smoke runs, and on real hardware.
+kernel, the backward the XLA-fused ref.  Every wrapper compiles for the
+TPU unless the caller passes ``interpret=True`` (the CPU tests do); there
+is no automatic switch, so a kernel never silently runs in the
+interpreter on a device.
 """
 
 from __future__ import annotations
@@ -21,25 +22,21 @@ from repro.kernels import ref as _ref
 from repro.kernels import rmsnorm as _rn
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def flash_attention(q, k, v, causal=True, window=0, q_offset=0):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def flash_attention(q, k, v, causal=True, window=0, q_offset=0, interpret=False):
     """(B,H,Sq,D) x (B,Kh,Skv,D)^2 -> (B,H,Sq,D); GQA via H//Kh groups."""
     return _fa.flash_attention_fwd(
         q, k, v, causal=causal, window=window, q_offset=q_offset,
-        interpret=_interpret(),
+        interpret=interpret,
     )
 
 
-def _fa_fwd(q, k, v, causal, window, q_offset):
-    out = flash_attention(q, k, v, causal, window, q_offset)
+def _fa_fwd(q, k, v, causal, window, q_offset, interpret):
+    out = flash_attention(q, k, v, causal, window, q_offset, interpret)
     return out, (q, k, v, out)
 
 
-def _fa_bwd(causal, window, q_offset, res, dout):
+def _fa_bwd(causal, window, q_offset, interpret, res, dout):
     """Blockwise recompute backward via the models/attention ref math."""
     from repro.models.attention import flash_ref
 
@@ -62,13 +59,13 @@ def _fa_bwd(causal, window, q_offset, res, dout):
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
-def chunk_reduce(dst, src, alpha: float = 1.0, block: int = 16 * 1024):
-    return _cr.chunk_reduce(dst, src, alpha=alpha, block=block, interpret=_interpret())
+def chunk_reduce(dst, src, alpha: float = 1.0, block: int = 16 * 1024, interpret: bool = False):
+    return _cr.chunk_reduce(dst, src, alpha=alpha, block=block, interpret=interpret)
 
 
-def dequant_add(dst, q, scale, qblock: int = 256):
-    return _cr.dequant_add(dst, q, scale, qblock=qblock, interpret=_interpret())
+def dequant_add(dst, q, scale, qblock: int = 256, interpret: bool = False):
+    return _cr.dequant_add(dst, q, scale, qblock=qblock, interpret=interpret)
 
 
-def rmsnorm(x, w, eps: float = 1e-6):
-    return _rn.rmsnorm(x, w, eps=eps, interpret=_interpret())
+def rmsnorm(x, w, eps: float = 1e-6, interpret: bool = False):
+    return _rn.rmsnorm(x, w, eps=eps, interpret=interpret)

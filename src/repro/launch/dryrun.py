@@ -1,7 +1,3 @@
-import os
-
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
 For each cell this driver:
@@ -18,10 +14,17 @@ Usage:
   PYTHONPATH=src python -m repro.launch.dryrun [--arch A] [--shape S]
       [--mesh single|multi|both] [--force] [--pod-sync hoplite_chain]
 
+A CPU-only tool (run it with ``JAX_PLATFORMS=cpu``): it forces 512 host
+devices before jax is imported; the production meshes need 256 or 512.
+
 A failure in any cell (sharding mismatch, OOM at compile, unsupported
 collective) is a bug in the system -- the driver prints FAIL and a
 nonzero exit code at the end.
 """
+
+import os
+
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 import argparse
 import json

@@ -1,21 +1,34 @@
 """Serving driver: load (or init) a model, run batched generation.
 
     PYTHONPATH=src python -m repro.launch.serve --arch rwkv6-1.6b --reduced \
-        --devices 8 --batch 4 --prompt-len 32 --new-tokens 16
+        --batch 4 --prompt-len 32 --new-tokens 16
+
+The mesh is ``(data=n, model=1)`` over every device jax reports.
 """
 
 from __future__ import annotations
 
 import argparse
-import os
 import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from repro.configs import get_config, reduced_config
+from repro.checkpoint.checkpoint import Checkpointer
+from repro.launch.cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
+from repro.models import transformer as T
+from repro.models.common import init_params
+from repro.serving.engine import Engine, ServeOptions
+from repro.train import step as TS
 
 
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
-    ap.add_argument("--devices", type=int, default=8)
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--new-tokens", type=int, default=16)
@@ -23,26 +36,11 @@ def main(argv=None):
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args(argv)
 
-    os.environ.setdefault(
-        "XLA_FLAGS", f"--xla_force_host_platform_device_count={args.devices}"
-    )
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from repro.configs import get_config, reduced_config
-    from repro.checkpoint.checkpoint import Checkpointer
-    from repro.launch.mesh import make_debug_mesh
-    from repro.models import transformer as T
-    from repro.models.common import init_params
-    from repro.serving.engine import Engine, ServeOptions
-    from repro.sharding import partitioning
-    from repro.train import step as TS
-
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced_config(cfg)
-    mesh = make_debug_mesh()
+    mesh = make_mesh()
     with jax.set_mesh(mesh):
         shardings = TS.state_shardings(cfg, mesh)["params"]
         if args.ckpt_dir:

@@ -68,9 +68,12 @@ def init_params(skel, key, dtype_override=None):
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
-def abstract_params(skel):
-    """ShapeDtypeStruct tree for AOT lowering (no device allocation)."""
-    return tree_map_params(lambda p: jax.ShapeDtypeStruct(p.shape, p.dtype), skel)
+def abstract_params(skel, dtype_override=None):
+    """ShapeDtypeStruct tree for AOT lowering (no device allocation); the
+    dtypes match ``init_params`` with the same ``dtype_override``."""
+    return tree_map_params(
+        lambda p: jax.ShapeDtypeStruct(p.shape, dtype_override or p.dtype), skel
+    )
 
 
 def param_bytes(skel) -> int:

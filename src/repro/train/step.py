@@ -282,7 +282,7 @@ def state_shardings(cfg: ModelConfig, mesh: Mesh, options: TrainOptions = TrainO
 
 def abstract_state(cfg: ModelConfig):
     skel = T.model_skel(cfg)
-    aparams = abstract_params(skel)
+    aparams = abstract_params(skel, jnp.dtype(cfg.param_dtype))  # as init_state
     f32 = lambda s: jax.ShapeDtypeStruct(s.shape, jnp.float32)
     return {
         "params": aparams,

@@ -22,8 +22,9 @@ def run_subprocess(body: str):
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
         from repro.core import collectives as C
+        from repro.launch.mesh import auto_mesh
 
-        mesh = jax.make_mesh((8,), ("x",))
+        mesh = auto_mesh((8,), ("x",))
         x = np.random.RandomState(0).rand(8, 1536).astype(np.float32)
         want = np.broadcast_to(x.sum(0, keepdims=True), x.shape)
 
@@ -99,7 +100,7 @@ def test_binomial_broadcast_all_roots():
 def test_pairwise_exchange_n2():
     run_subprocess(
         """
-        mesh2 = jax.make_mesh((2, 4), ("p", "x"))
+        mesh2 = auto_mesh((2, 4), ("p", "x"))
         xx = np.random.RandomState(1).rand(2, 4, 32).astype(np.float32)
         g = jax.shard_map(lambda a: C.chain_allreduce(a, "p", 8), mesh=mesh2,
                           in_specs=P("p", "x"), out_specs=P("p", "x"))

@@ -97,11 +97,12 @@ def test_train_step_loss_decreases_tiny_model():
         from repro.configs import ARCHS, reduced_config
         from repro.configs.base import ShapeSpec
         from repro.data import pipeline
+        from repro.launch.mesh import make_mesh
         from repro.train import step as TS
 
         cfg = reduced_config(ARCHS["stablelm-3b"])
         shape = ShapeSpec("t", 32, 4, "train")
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh()  # (data=1, model=1), Auto axes
         opts = TS.TrainOptions(
             num_microbatches=2,
             adamw=dataclasses.replace(TS.TrainOptions().adamw, lr=3e-3, warmup_steps=2),

@@ -1,5 +1,6 @@
 """Per-kernel validation: Pallas (interpret mode) vs pure-jnp oracles,
-swept over shapes and dtypes (system spec deliverable c)."""
+swept over shapes and dtypes.  Every call passes ``interpret=True``
+explicitly: the wrappers compile for the TPU unless told otherwise."""
 
 import numpy as np
 import pytest
@@ -41,7 +42,7 @@ def test_flash_attention_matches_ref(case, dtype):
     k = jnp.asarray(rng.randn(B, Kh, Skv, D), dtype) / np.sqrt(D)
     v = jnp.asarray(rng.randn(B, Kh, Skv, D), dtype)
     q_offset = Skv - Sq if Sq != Skv else 0
-    got = ops.flash_attention(q, k, v, causal, window, q_offset)
+    got = ops.flash_attention(q, k, v, causal, window, q_offset, True)
     want = ref.flash_attention_ref(q, k, v, causal, window, q_offset)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32), **tol(dtype)
@@ -56,7 +57,7 @@ def test_flash_attention_grad_matches_ref():
     v = jnp.asarray(rng.randn(B, Kh, S, D), jnp.float32)
 
     def f_kernel(q, k, v):
-        return jnp.sum(jnp.tanh(ops.flash_attention(q, k, v, True, 0, 0)))
+        return jnp.sum(jnp.tanh(ops.flash_attention(q, k, v, True, 0, 0, True)))
 
     def f_ref(q, k, v):
         return jnp.sum(jnp.tanh(ref.flash_attention_ref(q, k, v, True, 0, 0)))
@@ -79,7 +80,7 @@ def test_chunk_reduce_matches_ref(n, dtype, alpha):
     rng = np.random.RandomState(2)
     dst = jnp.asarray(rng.randn(n), dtype)
     src = jnp.asarray(rng.randn(n), dtype)
-    got = ops.chunk_reduce(dst, src, alpha=alpha)
+    got = ops.chunk_reduce(dst, src, alpha=alpha, interpret=True)
     want = ref.chunk_reduce_ref(dst, src, alpha=alpha)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32), **tol(dtype)
@@ -92,7 +93,7 @@ def test_dequant_add_matches_ref(n):
     dst = jnp.asarray(rng.randn(n), jnp.float32)
     payload = jnp.asarray(rng.randn(n), jnp.float32)
     q, scale = quantize_int8(payload)
-    got = ops.dequant_add(dst, q.reshape(-1), scale)
+    got = ops.dequant_add(dst, q.reshape(-1), scale, interpret=True)
     want = ref.dequant_add_ref(dst, q.reshape(-1), scale, 256)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
 
@@ -108,7 +109,7 @@ def test_rmsnorm_matches_ref(shape, dtype):
     rng = np.random.RandomState(4)
     x = jnp.asarray(rng.randn(*shape), dtype)
     w = jnp.asarray(rng.randn(shape[-1]) * 0.1, dtype)
-    got = ops.rmsnorm(x, w)
+    got = ops.rmsnorm(x, w, interpret=True)
     want = ref.rmsnorm_ref(x, w)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32), **tol(dtype)

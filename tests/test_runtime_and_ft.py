@@ -116,15 +116,16 @@ def test_elastic_remesh_restore():
         import jax, jax.numpy as jnp, numpy as np
         from repro.checkpoint.checkpoint import Checkpointer
         from repro.configs import ARCHS, reduced_config
+        from repro.launch.mesh import make_mesh
         from repro.train import step as TS
 
         cfg = reduced_config(ARCHS["stablelm-3b"])
         d = tempfile.mkdtemp()
-        mesh1 = jax.make_mesh((4, 2), ("data", "model"))
+        mesh1 = make_mesh(jax.devices(), model=2)  # (4, 2)
         with jax.set_mesh(mesh1):
             st = TS.init_state(cfg, jax.random.PRNGKey(0), mesh1)
             Checkpointer(d).save(7, st)
-        mesh2 = jax.make_mesh((2, 2), ("data", "model"))  # ELASTIC: fewer devices
+        mesh2 = make_mesh(jax.devices()[:4], model=2)  # ELASTIC: (2, 2), fewer devices
         with jax.set_mesh(mesh2):
             sh2 = TS.state_shardings(cfg, mesh2)
             step, st2 = Checkpointer(d).restore(TS.abstract_state(cfg), shardings=sh2)
