@@ -3,7 +3,8 @@
 Prints ``name,us_per_call,derived`` CSV rows.  Figures 5-9 run on the
 discrete-event simulator (the real Hoplite control plane over a modeled
 EC2 data plane); the chain-condition bench validates Appendix A; the TPU
-collective bench and the roofline report read compiled-HLO schedules.
+collective bench reads compiled-HLO schedules.  Chip measurements are the
+chip benchmark's (``benchmarks/chip/``, ``BENCHMARK.json``).
 
 ``--json PATH`` switches to the threaded *data-plane* suite
 (``bench_core_dataplane``: real bytes through ``LocalCluster``) and
@@ -52,7 +53,6 @@ def main() -> None:
         bench_rl,
         bench_serving_ensemble,
         bench_tpu_collectives,
-        roofline,
     )
 
     sections = [
@@ -65,7 +65,6 @@ def main() -> None:
         ("Section 5.3: ensemble serving", bench_serving_ensemble.run),
         ("Threaded data plane (real bytes)", bench_core_dataplane.run),
         ("TPU collective schedules", bench_tpu_collectives.run),
-        ("Roofline (from dry-run artifacts)", roofline.run),
     ]
     failures = 0
     for title, fn in sections:

@@ -503,6 +503,7 @@ def hoplite_psum(
     return chain_allreduce(x, axis_name, config.chunks_for(n, nbytes))
 
 
+@jax.named_scope("grad_sync")
 def grad_sync(
     grads,
     axis_name: str,

@@ -96,8 +96,9 @@ class Prefetcher:
         step = self.step
         try:
             while not self.stop.is_set():
-                batch = device_batch(self.cfg, self.shape, step, self.mesh, self.specs, self.seed)
-                self.q.put((step, batch))
+                with jax.profiler.TraceAnnotation("data/produce"):
+                    batch = device_batch(self.cfg, self.shape, step, self.mesh, self.specs, self.seed)
+                self.q.put((step, batch))  # back-pressure, outside the span
                 step += 1
         except Exception as e:  # noqa: BLE001 -- re-raised by the consumer
             self.q.put(e)

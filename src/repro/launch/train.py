@@ -47,6 +47,11 @@ POD_SYNCS = ("gspmd", "hoplite_chain", "hoplite_2d", "psum")
 
 def build_step(cfg: ModelConfig, shape: ShapeSpec, mesh, opts: TS.TrainOptions):
     """(jitted train step with the state donated, batch PartitionSpecs)."""
+    # The step's named scopes (train/step.py: SCOPES) live in its ops' metadata,
+    # which the persistent compile cache leaves out of its key by default: an
+    # executable cached from the same program without them would come back
+    # unnamed.
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     shardings = TS.state_shardings(cfg, mesh, opts)
     train_step = jax.jit(
         TS.make_train_step(cfg, mesh, shape, opts),
@@ -102,7 +107,8 @@ def main(argv=None):
             for step_idx, batch in feed:
                 if step_idx >= args.steps:
                     break
-                state, metrics = train_step(state, batch)
+                with jax.profiler.StepTraceAnnotation("train", step_num=step_idx):
+                    state, metrics = train_step(state, batch)
                 tokens_done += shape.global_batch * shape.seq_len
                 if (step_idx + 1) % args.log_every == 0:
                     dt = time.time() - t0

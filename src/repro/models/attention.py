@@ -280,6 +280,7 @@ def _positions_rope(cfg, p, q, k, q_pos, kv_pos, positions_3d=None):
     return q, k
 
 
+@jax.named_scope("attention")
 def attention_fwd(
     cfg,
     p,

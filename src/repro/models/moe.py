@@ -4,9 +4,10 @@ Dispatch uses the dense (one-hot combine) formulation: every expert
 computes on every token and results are combined with routing weights.
 Under GSPMD with experts sharded over the model axis (EP) this lowers to
 an all-to-all-free einsum program whose FLOPs are E/top_k times the active
-FLOPs -- the roofline section reports MODEL_FLOPS/HLO_FLOPs to expose
-exactly this, and the hillclimb replaces it with a gather-based dispatch
-(capacity-bounded) for the MoE cells.
+FLOPs -- a low model-FLOPs utilization (``benchmarks/chip/flops/`` over
+the peak in ``benchmarks/chip/peaks.json``) exposes exactly this, and the
+hillclimb replaces it with a gather-based dispatch (capacity-bounded) for
+the MoE cells.
 
 A gather-based (capacity-factor) dispatch is also provided
 (``moe_fwd_dropping``) and is selected by ``mode='dropping'``: tokens are

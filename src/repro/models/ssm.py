@@ -110,7 +110,8 @@ def _mamba_core(cfg, p, xz, conv_state, ssm_state, *, single_step: bool):
     # Discretization (dA = exp(delta (x) A), dBx = delta*B*x) is FUSED into
     # the scan body: materializing the (B,S,di,N) tensors costs N=16x the
     # scan's HBM traffic and made jamba train_4k memory-bound by ~3 orders
-    # of magnitude in the dry-run roofline (EXPERIMENTS §Perf, iteration 1).
+    # of magnitude: the dry-run's bytes over the HBM bandwidth in
+    # benchmarks/chip/peaks.json, against its FLOPs (benchmarks/chip/flops/).
     def step(h, inp):
         delta_t, B_t, C_t, x_t = inp  # (B,di), (B,N), (B,N), (B,di)
         dA_t = jnp.exp(delta_t[..., None] * A[None])  # (B,di,N), VMEM-local

@@ -112,6 +112,7 @@ def model_skel(cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 
 
+@jax.named_scope("mlp")
 def _ffn_part(cfg, lp, spec, x):
     h = apply_norm(cfg, lp["ln2"], x)
     if spec.moe:

@@ -53,6 +53,7 @@ def global_norm(tree) -> jax.Array:
     return jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32))) for g in leaves))
 
 
+@jax.named_scope("optimizer")
 def adamw_update(grads, opt_state, params, cfg: AdamWConfig):
     """Returns (new_params, new_opt_state, metrics)."""
     count = opt_state["count"] + 1

@@ -39,6 +39,14 @@ from repro.sharding import partitioning
 from repro.sharding.partitioning import ShardingOptions
 
 
+# The jax.named_scope names on the step's layers, one per layer boundary and
+# never nested: models/attention.attention_fwd, models/transformer._ffn_part,
+# optim/adamw.adamw_update and core/collectives.grad_sync.  Every op the
+# compiler makes inside one carries its name as a whole segment of op_name
+# (the device trace's tf_op): forward, rematerialized and backward.
+SCOPES = ("attention", "mlp", "optimizer", "grad_sync")
+
+
 @dataclasses.dataclass(frozen=True)
 class TrainOptions:
     num_microbatches: int = 1

@@ -97,3 +97,23 @@ def test_train_driver_builds_mesh_from_devices(tmp_path, pod_sync):
     else:  # one CPU device cannot hold a pod axis: refused, never padded
         assert proc.returncode != 0
         assert "pod axis needs at least 2" in proc.stderr
+
+
+def test_train_driver_marks_steps_and_batches_in_the_profiler_trace(tmp_path):
+    code = (
+        "import sys, jax; from repro.launch import train\n"
+        "jax.profiler.start_trace(sys.argv[1])\n"
+        "train.main(['--arch', 'whisper-medium', '--reduced', '--steps', '2', '--seq-len', '16',"
+        " '--global-batch', '2', '--log-every', '1'])\n"
+        "jax.profiler.stop_trace()\n"
+    )
+    proc = _run(["-c", code, str(tmp_path / "trace")], {"JAX_COMPILATION_CACHE_DIR": str(tmp_path)})
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    from jax._src.profiler import ProfileData
+
+    (path,) = (tmp_path / "trace").rglob("*.xplane.pb")
+    (host,) = [p for p in ProfileData.from_file(str(path)).planes if p.name == "/host:CPU"]
+    events = [e for line in host.lines for e in line.events]
+    steps = sorted(dict(e.stats)["step_num"] for e in events if e.name == "train")
+    assert steps == [0, 1]
+    assert sum(e.name == "data/produce" for e in events) >= 2
