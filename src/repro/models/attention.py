@@ -4,6 +4,17 @@ The training/prefill path uses a blocked streaming-softmax implementation
 (pure jnp "flash" algorithm: double lax.scan over query and key blocks,
 O(S * block) memory) so that 32k prefill never materializes an S x S score
 matrix -- required for the dry-run's memory analysis to be meaningful.
+
+Block contract (`block_plan`, a function of the lengths alone): a length
+keeps its largest power-of-two-halving divisor block (from 2048 query /
+1024 key positions) where that block is at least 128 or the whole length.
+Otherwise the length is padded up to a multiple of a lane-aligned block,
+the largest of 1024 / 512 / 256 / 128 that pads at most 1/8 of the padded
+length: whisper's 1500 frames run as 1536 in 512-key blocks, not in
+375 blocks of 4.  Pad keys are zeros at position -1, which the mask drops;
+pad query rows repeat the last position and are sliced off.  The padding
+lives inside the forward and backward (named scope ``kv_pad``), so the
+custom VJP saves nothing padded.
 The Pallas kernel in repro/kernels/flash_attention.py implements the same
 contract for the TPU target; kernels/ref.py delegates here.
 
@@ -14,6 +25,7 @@ under GSPMD (flash-decoding-style partial-softmax combine).
 
 from __future__ import annotations
 
+import contextlib
 import math
 from functools import partial
 from typing import Optional, Tuple
@@ -46,14 +58,57 @@ def attn_skel(cfg, cross: bool = False):
 # ---------------------------------------------------------------------------
 
 
-def _block_sizes(sq: int, skv: int) -> Tuple[int, int]:
-    qb = min(sq, 2048)
-    while sq % qb:
-        qb //= 2
-    kb = min(skv, 1024)
-    while skv % kb:
-        kb //= 2
-    return max(qb, 1), max(kb, 1)
+_LANE_BLOCKS = (1024, 512, 256, 128)
+
+
+def _axis_plan(n: int, cap: int) -> Tuple[int, int]:
+    """(block, padded length) for one axis of `block_plan`."""
+    b = min(n, cap)
+    while n % b:
+        b //= 2
+    if b >= _LANE_BLOCKS[-1] or b == n:
+        return b, n
+    for b in _LANE_BLOCKS:
+        pad = -n % b
+        # n > cap >= 1024 here, so 128 pads under 1/8 and the loop returns
+        if pad * 8 <= n + pad or b == _LANE_BLOCKS[-1]:
+            return b, n + pad
+
+
+def block_plan(sq: int, skv: int) -> Tuple[int, int, int, int]:
+    """(qb, kb, sq_pad, skv_pad): the blocks of the streaming softmax and the
+    lengths they tile; a length without a good divisor block is padded."""
+    qb, sq_pad = _axis_plan(sq, 2048)
+    kb, skv_pad = _axis_plan(skv, 1024)
+    return qb, kb, sq_pad, skv_pad
+
+
+def _pad_axis(x, n: int, axis: int, **how):
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, n - x.shape[axis])
+    return jnp.pad(x, widths, **how)
+
+
+def _unpad(x, n: int, axis: int):
+    return x if x.shape[axis] == n else lax.slice_in_dim(x, 0, n, axis=axis)
+
+
+def _pad_lengths(q, k, v, q_pos, kv_pos, sq_pad, skv_pad, rows=()):
+    """Pad q and the per-query `rows` with zeros to sq_pad, pad query positions
+    with the last one (it has a valid key), and pad k, v with zero keys at
+    position -1 (masked) to skv_pad."""
+    if sq_pad != q.shape[3]:
+        q, *rows = (_pad_axis(x, sq_pad, 3) for x in (q, *rows))
+        q_pos = _pad_axis(q_pos, sq_pad, 0, mode="edge")
+    if skv_pad != k.shape[2]:
+        k, v = _pad_axis(k, skv_pad, 2), _pad_axis(v, skv_pad, 2)
+        kv_pos = _pad_axis(kv_pos, skv_pad, 0, constant_values=-1)
+    return q, k, v, q_pos, kv_pos, tuple(rows)
+
+
+def _pad_scope(sq: int, skv: int, sq_pad: int, skv_pad: int):
+    padded = (sq_pad, skv_pad) != (sq, skv)
+    return jax.named_scope("kv_pad") if padded else contextlib.nullcontext()
 
 
 def _mask_for(qpos, kpos, causal: bool, window: int):
@@ -80,9 +135,17 @@ def flash_ref(q, k, v, q_pos, kv_pos, causal, window=0):
 
 
 def _flash_fwd_impl(q, k, v, q_pos, kv_pos, causal, window):
+    Sq, Skv = q.shape[3], k.shape[2]
+    qb, kb, sq_pad, skv_pad = block_plan(Sq, Skv)
+    with _pad_scope(Sq, Skv, sq_pad, skv_pad):
+        q, k, v, q_pos, kv_pos, _ = _pad_lengths(q, k, v, q_pos, kv_pos, sq_pad, skv_pad)
+        out, lse = _flash_fwd_blocks(q, k, v, q_pos, kv_pos, causal, window, qb, kb)
+        return _unpad(out, Sq, 3), _unpad(lse, Sq, 3)
+
+
+def _flash_fwd_blocks(q, k, v, q_pos, kv_pos, causal, window, qb, kb):
     B, K, G, Sq, D = q.shape
     Skv = k.shape[2]
-    qb, kb = _block_sizes(Sq, Skv)
     nq, ns = Sq // qb, Skv // kb
     scale = 1.0 / math.sqrt(D)
 
@@ -139,13 +202,31 @@ def _flash_bwd(causal, window, res, dout):
     dq = ds k * scale ; dk = ds^T q * scale.
     """
     q, k, v, q_pos, kv_pos, out, lse = res
+    Sq, Skv = q.shape[3], k.shape[2]
+    qb, kb, sq_pad, skv_pad = block_plan(Sq, Skv)
+    with _pad_scope(Sq, Skv, sq_pad, skv_pad):
+        delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+        # pad rows: zero q and dout give a finite p and zero ds and dv terms
+        qp, kp, vp, q_pos, kv_pos, (dout, lse, delta) = _pad_lengths(
+            q, k, v, q_pos, kv_pos, sq_pad, skv_pad, (dout, lse, delta)
+        )
+        dq, dkf, dvf = _flash_bwd_blocks(
+            qp, kp, vp, q_pos, kv_pos, dout, lse, delta, causal, window, qb, kb
+        )
+        return (
+            _unpad(dq, Sq, 3).astype(q.dtype),
+            _unpad(dkf, Skv, 2).astype(k.dtype),
+            _unpad(dvf, Skv, 2).astype(v.dtype),
+            None,
+            None,
+        )
+
+
+def _flash_bwd_blocks(q, k, v, q_pos, kv_pos, dout, lse, delta, causal, window, qb, kb):
     B, K, G, Sq, D = q.shape
     Skv = k.shape[2]
-    qb, kb = _block_sizes(Sq, Skv)
     nq, ns = Sq // qb, Skv // kb
     scale = 1.0 / math.sqrt(D)
-
-    delta = jnp.sum(dout.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
 
     qs = q.reshape(B, K, G, nq, qb, D).transpose(3, 0, 1, 2, 4, 5)
     dos = dout.reshape(B, K, G, nq, qb, D).transpose(3, 0, 1, 2, 4, 5)
@@ -205,13 +286,7 @@ def _flash_bwd(causal, window, res, dout):
         q_step, (dk0, dv0), (qs, dos, lses, deltas, qp)
     )
     dq = dq_blocks.transpose(1, 2, 3, 0, 4, 5).reshape(B, K, G, Sq, D)
-    return (
-        dq.astype(q.dtype),
-        dkf.astype(k.dtype),
-        dvf.astype(v.dtype),
-        None,
-        None,
-    )
+    return dq, dkf, dvf
 
 
 flash_ref.defvjp(_flash_fwd, _flash_bwd)

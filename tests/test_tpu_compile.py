@@ -19,8 +19,10 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 from repro.core import collectives as C
 from repro.kernels import ops
 from repro.launch.mesh import auto_mesh
+from repro.models.attention import flash_ref
 
 MIB = 1 << 20
+GIB = 1 << 30
 
 
 @pytest.fixture(scope="module")
@@ -76,6 +78,23 @@ def test_dequant_add_compiles(one_chip):
 def test_flash_attention_fwd_compiles(one_chip):
     q = _spec((8, 16, 512, 64), jnp.bfloat16, one_chip)  # whisper-medium heads
     assert "tpu_custom_call" in _hlo(ops.flash_attention, q, q, q)
+
+
+@pytest.mark.parametrize("sq", [1500, 448])
+def test_flash_ref_fwd_bwd_over_whisper_frames_compiles(one_chip, sq):
+    """whisper-medium's encoder self- and decoder cross-attention over 1500
+    frames, forward and backward, in blocks small enough to leave the train
+    step's 7.9 GiB of state its room."""
+    q = _spec((8, 16, 1, sq, 64), jnp.bfloat16, one_chip)
+    kv = _spec((8, 16, 1500, 64), jnp.bfloat16, one_chip)
+
+    def fwd_bwd(q, k, v):
+        q_pos, kv_pos = jnp.arange(sq), jnp.arange(1500)
+        out, vjp = jax.vjp(lambda *a: flash_ref(*a, q_pos, kv_pos, False), q, k, v)
+        return out, vjp(out)
+
+    compiled = jax.jit(fwd_bwd).lower(q, kv, kv).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * GIB
 
 
 def test_rmsnorm_compiles(one_chip):
