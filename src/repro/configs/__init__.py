@@ -1,4 +1,5 @@
-"""Architecture registry: the 10 assigned configs + reduced smoke variants."""
+"""Architecture registry: the 10 assigned configs, Granite 4.0-H Micro, and
+reduced smoke variants."""
 
 from __future__ import annotations
 
@@ -23,6 +24,7 @@ from repro.configs.qwen3_14b import CONFIG as QWEN3
 from repro.configs.stablelm_3b import CONFIG as STABLELM
 from repro.configs.gemma3_27b import CONFIG as GEMMA3
 from repro.configs.whisper_medium import CONFIG as WHISPER
+from repro.configs.granite_4_0_h_micro import CONFIG as GRANITE_H_MICRO
 
 ARCHS: Dict[str, ModelConfig] = {
     c.name: c
@@ -37,6 +39,7 @@ ARCHS: Dict[str, ModelConfig] = {
         STABLELM,
         GEMMA3,
         WHISPER,
+        GRANITE_H_MICRO,
     ]
 }
 
@@ -79,6 +82,7 @@ def reduced_config(cfg: ModelConfig) -> ModelConfig:
     mrope = (2, 3, 3) if cfg.rope == "mrope" else ()
     assert not mrope or sum(mrope) == half
     nblocks = min(2, cfg.num_blocks)
+    ssm_heads = min(cfg.ssm_heads, 4)
     return dataclasses.replace(
         cfg,
         name=cfg.name + "-smoke",
@@ -93,6 +97,9 @@ def reduced_config(cfg: ModelConfig) -> ModelConfig:
         num_experts=4 if cfg.num_experts else 0,
         top_k=min(cfg.top_k, 2),
         ssm_state_dim=8,
+        ssm_heads=ssm_heads,
+        ssm_head_dim=cfg.ssm_expand * 64 // ssm_heads if ssm_heads else cfg.ssm_head_dim,
+        ssm_chunk=min(cfg.ssm_chunk, 8),
         rwkv_head_size=16,
         encoder_layers=2 if cfg.encoder_layers else 0,
         encoder_seq=32 if cfg.encoder_seq else 0,

@@ -38,7 +38,7 @@ SHAPES_BY_NAME = {s.name: s for s in ALL_SHAPES}
 class LayerSpec:
     """One position in the repeating layer pattern."""
 
-    kind: str  # "attn" | "mamba" | "rwkv"
+    kind: str  # "attn" | "mamba" | "mamba2" | "rwkv"
     attention: str = "full"  # "full" | "window"
     window: int = 0  # only for attention == "window"
     moe: bool = False  # MoE FFN at this position?
@@ -74,11 +74,26 @@ class ModelConfig:
     ssm_expand: int = 2
     ssm_conv_width: int = 4
     rwkv_head_size: int = 64
+    # Mamba-2 (SSD) options: ssm_heads heads of ssm_head_dim channels (their
+    # product is ssm_expand * d_model), B and C shared by ssm_groups groups
+    # of heads, the SSD computed in chunks of ssm_chunk positions
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_groups: int = 1
+    ssm_chunk: int = 256
     # encoder-decoder
     encoder_layers: int = 0
     encoder_seq: int = 0  # stub frontend output length (e.g. audio frames)
     # misc
-    norm: str = "rmsnorm"  # rmsnorm | layernorm
+    norm: str = "rmsnorm"  # rmsnorm (scale 1 + w) | rmsnorm_plain (scale w) | layernorm
+    norm_eps: Optional[float] = None  # None: the norm's default (1e-6 rms, 1e-5 layer)
+    # muP-style multipliers (Granite): the embedding times embedding_multiplier,
+    # every residual branch times residual_multiplier, the logits over
+    # logits_divisor, attention scores times attention_scale (None: 1/sqrt(head_dim))
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_divisor: float = 1.0
+    attention_scale: Optional[float] = None
     act: str = "swiglu"  # swiglu | gelu
     tie_embeddings: bool = False
     vocab_pad_to: int = 16
@@ -148,15 +163,20 @@ class ModelConfig:
                     block += d * 2 * di + di * self.ssm_conv_width + di * (
                         2 * self.ssm_state_dim + 1
                     ) + di * d + di * (di // 16 + 2 * self.ssm_state_dim)
+                elif spec.kind == "mamba2":
+                    H, G, N = self.ssm_heads, self.ssm_groups, self.ssm_state_dim
+                    di, conv = H * self.ssm_head_dim, H * self.ssm_head_dim + 2 * G * N
+                    block += d * (di + conv + H) + self.ssm_conv_width * conv + conv
+                    block += 3 * H + di + di * d
                 elif spec.kind == "rwkv":
                     block += 4 * d * d + d * (self.d_ff) * 2
-                if spec.kind in ("attn", "mamba"):
+                if spec.kind in ("attn", "mamba", "mamba2"):
                     n_ffn = 3 if self.act == "swiglu" else 2
                     if spec.moe:
                         block += self.num_experts * n_ffn * d * f + d * self.num_experts
                         if self.shared_expert:
                             block += n_ffn * d * f
-                    elif spec.kind == "attn":
+                    elif spec.kind in ("attn", "mamba2"):
                         block += n_ffn * d * f
                 P += block * nblocks
         P += v * d * (1 if self.tie_embeddings else 2)

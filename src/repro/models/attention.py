@@ -120,9 +120,10 @@ def _mask_for(qpos, kpos, causal: bool, window: int):
     return mask  # (qb, kb)
 
 
-@partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def flash_ref(q, k, v, q_pos, kv_pos, causal, window=0):
-    """Streaming-softmax attention; returns (B, K, G, Sq, D).
+@partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def flash_ref(q, k, v, q_pos, kv_pos, causal, window=0, scale=None):
+    """Streaming-softmax attention; returns (B, K, G, Sq, D).  Scores are
+    q.k times ``scale`` (None: 1/sqrt(D)).
 
     custom_vjp: the backward pass recomputes score blocks from (q,k,v,lse)
     instead of saving the per-block probabilities -- without this, autodiff
@@ -130,24 +131,28 @@ def flash_ref(q, k, v, q_pos, kv_pos, causal, window=0):
     explodes (observed 8 GiB/buffer on the 3B train_4k dry-run).  This is
     the exact contract the Pallas kernel implements on TPU.
     """
-    out, _lse = _flash_fwd_impl(q, k, v, q_pos, kv_pos, causal, window)
+    out, _lse = _flash_fwd_impl(q, k, v, q_pos, kv_pos, causal, window, scale)
     return out
 
 
-def _flash_fwd_impl(q, k, v, q_pos, kv_pos, causal, window):
+def _score_scale(scale, D: int) -> float:
+    return 1.0 / math.sqrt(D) if scale is None else scale
+
+
+def _flash_fwd_impl(q, k, v, q_pos, kv_pos, causal, window, scale):
     Sq, Skv = q.shape[3], k.shape[2]
     qb, kb, sq_pad, skv_pad = block_plan(Sq, Skv)
     with _pad_scope(Sq, Skv, sq_pad, skv_pad):
         q, k, v, q_pos, kv_pos, _ = _pad_lengths(q, k, v, q_pos, kv_pos, sq_pad, skv_pad)
-        out, lse = _flash_fwd_blocks(q, k, v, q_pos, kv_pos, causal, window, qb, kb)
+        out, lse = _flash_fwd_blocks(q, k, v, q_pos, kv_pos, causal, window, qb, kb, scale)
         return _unpad(out, Sq, 3), _unpad(lse, Sq, 3)
 
 
-def _flash_fwd_blocks(q, k, v, q_pos, kv_pos, causal, window, qb, kb):
+def _flash_fwd_blocks(q, k, v, q_pos, kv_pos, causal, window, qb, kb, scale):
     B, K, G, Sq, D = q.shape
     Skv = k.shape[2]
     nq, ns = Sq // qb, Skv // kb
-    scale = 1.0 / math.sqrt(D)
+    scale = _score_scale(scale, D)
 
     qs = q.reshape(B, K, G, nq, qb, D).transpose(3, 0, 1, 2, 4, 5)
     qp = q_pos.reshape(nq, qb)
@@ -190,12 +195,12 @@ def _flash_fwd_blocks(q, k, v, q_pos, kv_pos, causal, window, qb, kb):
     return out, lse
 
 
-def _flash_fwd(q, k, v, q_pos, kv_pos, causal, window):
-    out, lse = _flash_fwd_impl(q, k, v, q_pos, kv_pos, causal, window)
+def _flash_fwd(q, k, v, q_pos, kv_pos, causal, window, scale):
+    out, lse = _flash_fwd_impl(q, k, v, q_pos, kv_pos, causal, window, scale)
     return out, (q, k, v, q_pos, kv_pos, out, lse)
 
 
-def _flash_bwd(causal, window, res, dout):
+def _flash_bwd(causal, window, scale, res, dout):
     """Blockwise flash backward: recompute p per (q,kv) block pair.
 
     dv = p^T dout ; dp = dout v^T ; ds = p * (dp - rowsum(dout*out)) ;
@@ -211,7 +216,7 @@ def _flash_bwd(causal, window, res, dout):
             q, k, v, q_pos, kv_pos, sq_pad, skv_pad, (dout, lse, delta)
         )
         dq, dkf, dvf = _flash_bwd_blocks(
-            qp, kp, vp, q_pos, kv_pos, dout, lse, delta, causal, window, qb, kb
+            qp, kp, vp, q_pos, kv_pos, dout, lse, delta, causal, window, qb, kb, scale
         )
         return (
             _unpad(dq, Sq, 3).astype(q.dtype),
@@ -222,11 +227,11 @@ def _flash_bwd(causal, window, res, dout):
         )
 
 
-def _flash_bwd_blocks(q, k, v, q_pos, kv_pos, dout, lse, delta, causal, window, qb, kb):
+def _flash_bwd_blocks(q, k, v, q_pos, kv_pos, dout, lse, delta, causal, window, qb, kb, scale):
     B, K, G, Sq, D = q.shape
     Skv = k.shape[2]
     nq, ns = Sq // qb, Skv // kb
-    scale = 1.0 / math.sqrt(D)
+    scale = _score_scale(scale, D)
 
     qs = q.reshape(B, K, G, nq, qb, D).transpose(3, 0, 1, 2, 4, 5)
     dos = dout.reshape(B, K, G, nq, qb, D).transpose(3, 0, 1, 2, 4, 5)
@@ -299,10 +304,11 @@ def decode_attend(
     kv_positions: jax.Array,  # (S,) true token position per slot; < 0 invalid
     t: jax.Array,  # scalar: current position
     window: int = 0,
+    scale: Optional[float] = None,  # None: 1/sqrt(D)
 ) -> jax.Array:
     """One-token attention over the cache.  Under GSPMD the length
     reductions become partial-softmax combines across cache shards."""
-    scale = 1.0 / math.sqrt(q.shape[-1])
+    scale = _score_scale(scale, q.shape[-1])
     s = jnp.einsum(
         "bkgqd,bskd->bkgqs", q, k_cache, preferred_element_type=jnp.float32
     ) * scale
@@ -391,6 +397,7 @@ def attention_fwd(
         qh, kh, vh, q_pos, kv_pos,
         causal=causal and not cross,
         window=spec.window if spec.attention == "window" else 0,
+        scale=cfg.attention_scale,
     )
     out = out.transpose(0, 3, 1, 2, 4).reshape(B, Sq, H * D)
     return dense(out, p["wo"])
@@ -440,7 +447,8 @@ def attention_decode(
         # slots valid (their positions are 0..C-1, always <= t)
         qh = q.transpose(0, 2, 3, 1, 4)
         kv_positions = jnp.arange(C, dtype=jnp.int32)
-        out = decode_attend(qh, k_cache, v_cache, kv_positions, jnp.int32(C - 1))
+        out = decode_attend(qh, k_cache, v_cache, kv_positions, jnp.int32(C - 1),
+                            scale=cfg.attention_scale)
     else:
         xk = dense(x, p["wk"]).reshape(B, 1, K, D)
         xv = dense(x, p["wv"]).reshape(B, 1, K, D)
@@ -459,6 +467,7 @@ def attention_decode(
         out = decode_attend(
             qh, k_cache, v_cache, kv_positions, t,
             window=spec.window if spec.attention == "window" else 0,
+            scale=cfg.attention_scale,
         )
     out = out.transpose(0, 3, 1, 2, 4).reshape(B, 1, H * D)
     return dense(out, p["wo"]), (k_cache, v_cache)

@@ -91,11 +91,13 @@ def param_elems(skel) -> int:
 # ---------------------------------------------------------------------------
 
 
-def rmsnorm(x, w, eps=1e-6):
+def rmsnorm(x, w, eps=1e-6, offset=1.0):
+    """x / rms(x) * (offset + w): offset 1 keeps w as an offset from a unit
+    scale (zeros at init), offset 0 makes w the scale itself."""
     x32 = x.astype(jnp.float32)
     var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
     out = x32 * jax.lax.rsqrt(var + eps)
-    return (out * (1.0 + w.astype(jnp.float32))).astype(x.dtype)
+    return (out * (offset + w.astype(jnp.float32))).astype(x.dtype)
 
 
 def layernorm(x, w, b, eps=1e-5):
@@ -110,13 +112,18 @@ def norm_skel(cfg, dim=None):
     d = dim or cfg.d_model
     if cfg.norm == "rmsnorm":
         return {"w": Param((d,), ("embed",), init="zeros")}
+    if cfg.norm == "rmsnorm_plain":
+        return {"w": Param((d,), ("embed",), init="ones")}
     return {"w": Param((d,), ("embed",), init="ones"), "b": Param((d,), ("embed",), init="zeros")}
 
 
 def apply_norm(cfg, p, x):
+    eps = {} if cfg.norm_eps is None else {"eps": cfg.norm_eps}
     if cfg.norm == "rmsnorm":
-        return rmsnorm(x, p["w"])
-    return layernorm(x, p["w"], p["b"])
+        return rmsnorm(x, p["w"], **eps)
+    if cfg.norm == "rmsnorm_plain":
+        return rmsnorm(x, p["w"], offset=0.0, **eps)
+    return layernorm(x, p["w"], p["b"], **eps)
 
 
 # Cross-shard partial-sum dtype for TP-sharded contractions.  f32 partials

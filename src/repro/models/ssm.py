@@ -1,10 +1,12 @@
-"""State-space blocks: Mamba (Jamba's mixer) and RWKV-6 ("Finch").
+"""State-space blocks: Mamba (Jamba's mixer), Mamba-2 (Granite 4.0-H's
+mixer) and RWKV-6 ("Finch").
 
-Both are linear-state recurrences implemented with ``lax.scan`` over time
-(the TPU-friendly chunked-parallel form is a §Perf hillclimb option for
-the SSM cells; the scan form is the correctness baseline and is what the
-dry-run lowers).  Decode carries O(1) state per layer -- these are the
-architectures for which long_500k is the showcase cell.
+Mamba and RWKV-6 are linear-state recurrences implemented with ``lax.scan``
+over time (the scan form is the correctness baseline and is what the
+dry-run lowers).  Mamba-2 runs its scalar-decay recurrence as the chunked
+SSD algorithm: matmuls within chunks and a scan over chunk states only.
+Decode carries O(1) state per layer -- these are the architectures for
+which long_500k is the showcase cell.
 
 Shapes use (B, S, d) activations; state trees are dicts of arrays so the
 serving engine can thread them generically like KV caches.
@@ -18,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.models.common import Param, dense
+from repro.models.common import Param, dense, rmsnorm
 
 
 def chunked_scan(step, init, xs, seq_len: int, chunk: int = 128):
@@ -176,6 +178,150 @@ def mamba_decode(cfg, p, x, state: Dict[str, jax.Array]):
     xz = dense(x, p["in_proj"])  # (B,1,2di)
     y, conv, ssm = _mamba_core(cfg, p, xz, state["conv"], state["ssm"], single_step=True)
     return dense(y, p["out_proj"]), {"conv": conv, "ssm": ssm}
+
+
+# ---------------------------------------------------------------------------
+# Mamba-2 (SSD, arXiv:2405.21060), as in Granite 4.0-H
+# ---------------------------------------------------------------------------
+
+
+def mamba2_dims(cfg) -> Tuple[int, int, int, int, int]:
+    """(heads H, head size P, groups G, state size N, conv channels H*P + 2*G*N)."""
+    H, P, G, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state_dim
+    return H, P, G, N, H * P + 2 * G * N
+
+
+def mamba2_skel(cfg):
+    d = cfg.d_model
+    H, P, _, _, conv = mamba2_dims(cfg)
+    di = H * P
+    return {
+        "in_proj": Param((d, di + conv + H), ("embed", None)),  # z | x B C | dt
+        "conv_w": Param((cfg.ssm_conv_width, conv), (None, None), scale=0.5),
+        "conv_b": Param((conv,), (None,), init="zeros"),
+        "dt_bias": Param((H,), (None,), init="zeros"),
+        "A_log": Param((H,), (None,), init="zeros"),
+        "D": Param((H,), (None,), init="ones"),
+        "norm": {"w": Param((di,), ("ssm",), init="ones")},
+        "out_proj": Param((di, d), ("ssm", "embed")),
+    }
+
+
+def ssd(x, dt, A, B, C, chunk: int, state=None):
+    """The recurrence S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T, y_t = S_t C_t
+    per head, computed in chunks of ``chunk`` positions (SSD, Mamba-2 §6).
+
+    x (b, s, h, p); dt (b, s, h) f32, >= 0; A (h,) f32, < 0; B, C (b, s, g, n),
+    group g serving heads g*h/g..; state (b, h, p, n) f32 or None (zeros).
+    Returns y (b, s, h, p) f32 and the state after the last position.  The
+    decays, their cumulative sums and the states are f32; x, B and C enter
+    the matmuls in their own dtype, accumulated in f32.  A length that is
+    not a multiple of ``chunk`` is padded with dt = 0, which leaves the
+    state exactly as it was.
+    """
+    f32 = jnp.float32
+    b, s, h, p = x.shape
+    g, n = B.shape[-2:]
+    pad = -s % chunk
+    if pad:
+        x, dt, B, C = (jnp.pad(t, [(0, 0), (0, pad)] + [(0, 0)] * (t.ndim - 2)) for t in (x, dt, B, C))
+    c, l = (s + pad) // chunk, chunk
+    xs = (x.astype(f32) * dt[..., None]).astype(x.dtype).reshape(b, c, l, g, h // g, p)
+    Bs, Cs = B.reshape(b, c, l, g, n), C.reshape(b, c, l, g, n)
+    cs = jnp.cumsum((dt * A).reshape(b, c, l, g, h // g), axis=2)  # log decay from the chunk's start
+    # 1. within each chunk: y_i = sum_{j <= i} exp(cs_i - cs_j) (C_i . B_j) x_j
+    cb = jnp.einsum("bclgn,bcsgn->bcgls", Cs, Bs, preferred_element_type=f32)
+    seg = jnp.moveaxis(cs, 2, -1)  # (b, c, g, h/g, l)
+    causal = jnp.tril(jnp.ones((l, l), bool))
+    decay = jnp.exp(jnp.where(causal, seg[..., :, None] - seg[..., None, :], -jnp.inf))
+    m = (decay * cb[:, :, :, None]).astype(x.dtype)
+    y = jnp.einsum("bcghls,bcsghp->bclghp", m, xs, preferred_element_type=f32)
+    # 2. each chunk's own state: sum_j exp(cs_last - cs_j) x_j B_j^T
+    xw = (xs.astype(f32) * jnp.exp(cs[:, :, -1:] - cs)[..., None]).astype(x.dtype)
+    own = jnp.einsum("bclghp,bclgn->bcghpn", xw, Bs, preferred_element_type=f32)
+
+    # 3. the state entering each chunk, by a scan over the chunks
+    def carry(S, inp):
+        st, dec = inp
+        return S * dec[..., None, None] + st, S
+
+    S0 = jnp.zeros((b, g, h // g, p, n), f32) if state is None else state.reshape(b, g, h // g, p, n)
+    final, entering = lax.scan(carry, S0, (jnp.moveaxis(own, 1, 0), jnp.moveaxis(jnp.exp(cs[:, :, -1]), 1, 0)))
+    # 4. what the entering state gives: exp(cs_i) C_i . S
+    y_in = jnp.einsum("bclgn,cbghpn->bclghp", Cs, entering, preferred_element_type=f32)
+    y = y + y_in * jnp.exp(cs)[..., None]
+    return y.reshape(b, c * l, h, p)[:, :s], final.reshape(b, h, p, n)
+
+
+def _ssd_step(x, dt, A, B, C, state):
+    """One position of the recurrence: x (b, h, p), dt (b, h), B, C (b, g, n)."""
+    b, h, p = x.shape
+    g = B.shape[1]
+    rep = lambda t: jnp.repeat(t.astype(jnp.float32), h // g, axis=1)  # (b, h, n)
+    dA = jnp.exp(dt * A)[..., None, None]
+    state = dA * state + (dt[..., None] * x.astype(jnp.float32))[..., None] * rep(B)[:, :, None]
+    return jnp.einsum("bhpn,bhn->bhp", state, rep(C)), state
+
+
+def _causal_conv(x, w, b, prev):
+    """Depthwise causal conv over time, in f32: x (B, S, C), w (W, C), prev
+    (B, W-1, C) the inputs before x.  Returns (out, the last W-1 inputs)."""
+    W, S = w.shape[0], x.shape[1]
+    xpad = jnp.concatenate([prev.astype(x.dtype), x], axis=1).astype(jnp.float32)
+    out = sum(xpad[:, i : i + S] * w[i].astype(jnp.float32) for i in range(W))
+    return out + b.astype(jnp.float32), xpad[:, S:].astype(x.dtype)
+
+
+def _mamba2_mix(cfg, p, h, conv_prev, state):
+    """The mixer on the normed input h (B, S, d): in_proj, conv, SSD, gated
+    norm, out_proj.  Returns (out, the conv's last inputs, the SSD state)."""
+    with jax.named_scope("ssm"):
+        H, P, G, N, conv = mamba2_dims(cfg)
+        di = H * P
+        Bsz, S = h.shape[:2]
+        z, xbc, dt = jnp.split(dense(h, p["in_proj"]), [di, di + conv], axis=-1)
+        xbc, conv_next = _causal_conv(xbc, p["conv_w"], p["conv_b"], conv_prev)
+        x, Bm, Cm = jnp.split(jax.nn.silu(xbc).astype(h.dtype), [di, di + G * N], axis=-1)
+        x = x.reshape(Bsz, S, H, P)
+        Bm, Cm = Bm.reshape(Bsz, S, G, N), Cm.reshape(Bsz, S, G, N)
+        delta = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
+        A = -jnp.exp(p["A_log"].astype(jnp.float32))
+        if S == 1 and state is not None:
+            y, state = _ssd_step(x[:, 0], delta[:, 0], A, Bm[:, 0], Cm[:, 0], state)
+            y = y[:, None]
+        else:
+            with jax.named_scope("ssd"):
+                y, state = ssd(x, delta, A, Bm, Cm, cfg.ssm_chunk, state)
+        y = y + x.astype(jnp.float32) * p["D"].astype(jnp.float32)[:, None]
+        y = y.reshape(Bsz, S, di) * jax.nn.silu(z.astype(jnp.float32))
+        eps = 1e-6 if cfg.norm_eps is None else cfg.norm_eps
+        y = rmsnorm(y, p["norm"]["w"], eps=eps, offset=0.0).astype(h.dtype)
+        return dense(y, p["out_proj"]), conv_next, state
+
+
+def mamba2_init_state(cfg, batch: int, dtype=jnp.float32):
+    H, P, _, N, conv = mamba2_dims(cfg)
+    return {
+        "conv": jnp.zeros((batch, cfg.ssm_conv_width - 1, conv), dtype),
+        "ssm": jnp.zeros((batch, H, P, N), jnp.float32),
+    }
+
+
+def mamba2_fwd(cfg, p, x):
+    """Training forward (fresh state)."""
+    out, _, _ = _mamba2_mix(cfg, p, x, mamba2_init_state(cfg, x.shape[0], x.dtype)["conv"], None)
+    return out
+
+
+def mamba2_prefill(cfg, p, x):
+    """Forward returning the state for subsequent decode."""
+    out, conv, ssm = _mamba2_mix(cfg, p, x, mamba2_init_state(cfg, x.shape[0], x.dtype)["conv"], None)
+    return out, {"conv": conv, "ssm": ssm}
+
+
+def mamba2_decode(cfg, p, x, state: Dict[str, jax.Array]):
+    out, conv, ssm = _mamba2_mix(cfg, p, x, state["conv"], state["ssm"])
+    return out, {"conv": conv, "ssm": ssm}
 
 
 # ---------------------------------------------------------------------------

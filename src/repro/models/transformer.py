@@ -49,6 +49,8 @@ def layer_skel(cfg: ModelConfig, spec: LayerSpec, cross: bool = False):
         s["attn"] = attn.attn_skel(cfg)
     elif spec.kind == "mamba":
         s["mixer"] = ssm_mod.mamba_skel(cfg)
+    elif spec.kind == "mamba2":
+        s["mixer"] = ssm_mod.mamba2_skel(cfg)
     elif spec.kind == "rwkv":
         s["rwkv"] = ssm_mod.rwkv_skel(cfg)
         s["ln2"] = norm_skel(cfg)
@@ -112,6 +114,12 @@ def model_skel(cfg: ModelConfig):
 # ---------------------------------------------------------------------------
 
 
+def _residual(cfg, x, y):
+    """x + y, the branch y scaled by ``cfg.residual_multiplier`` where that is not 1."""
+    r = cfg.residual_multiplier
+    return x + y if r == 1.0 else x + y * jnp.asarray(r, y.dtype)
+
+
 @jax.named_scope("mlp")
 def _ffn_part(cfg, lp, spec, x):
     h = apply_norm(cfg, lp["ln2"], x)
@@ -122,7 +130,7 @@ def _ffn_part(cfg, lp, spec, x):
             out, aux = moe_mod.moe_fwd(cfg, lp["moe"], h)
     else:
         out, aux = moe_mod.ffn_fwd(cfg, lp["ffn"], h), 0.0
-    return x + out, aux
+    return _residual(cfg, x, out), aux
 
 
 def layer_fwd(cfg, spec, lp, x, q_pos, positions_3d=None, enc_out=None, causal=True):
@@ -138,16 +146,18 @@ def layer_fwd(cfg, spec, lp, x, q_pos, positions_3d=None, enc_out=None, causal=T
         )
     h = apply_norm(cfg, lp["ln1"], x)
     if spec.kind == "attn":
-        x = x + attn.attention_fwd(
+        x = _residual(cfg, x, attn.attention_fwd(
             cfg, lp["attn"], h, spec, q_pos, positions_3d, causal=causal
-        )
+        ))
+    elif spec.kind == "mamba2":
+        x = _residual(cfg, x, ssm_mod.mamba2_fwd(cfg, lp["mixer"], h))
     else:  # mamba
-        x = x + ssm_mod.mamba_fwd(cfg, lp["mixer"], h)
+        x = _residual(cfg, x, ssm_mod.mamba_fwd(cfg, lp["mixer"], h))
     if enc_out is not None and "cross" in lp:
         h = apply_norm(cfg, lp["ln_cross"], x)
-        x = x + attn.attention_fwd(
+        x = _residual(cfg, x, attn.attention_fwd(
             cfg, lp["cross"], h, spec, q_pos, kv_x=enc_out
-        )
+        ))
     return _ffn_part(cfg, lp, spec, x)
 
 
@@ -163,7 +173,7 @@ def layer_prefill(cfg, spec, lp, x, q_pos, cache_len, positions_3d=None, enc_out
     h = apply_norm(cfg, lp["ln1"], x)
     cache = None
     if spec.kind == "attn":
-        x = x + attn.attention_fwd(cfg, lp["attn"], h, spec, q_pos, positions_3d)
+        x = _residual(cfg, x, attn.attention_fwd(cfg, lp["attn"], h, spec, q_pos, positions_3d))
         k, v = attn.attention_prefill_kv(cfg, lp["attn"], h, q_pos, positions_3d)
         B, S = k.shape[0], k.shape[1]
         C = cache_len
@@ -179,13 +189,13 @@ def layer_prefill(cfg, spec, lp, x, q_pos, cache_len, positions_3d=None, enc_out
             kc = jnp.roll(kw, roll, axis=1)
             vc = jnp.roll(vw, roll, axis=1)
         cache = {"k": kc, "v": vc}
-    else:  # mamba
-        y, state = ssm_mod.mamba_prefill(cfg, lp["mixer"], h)
-        x = x + y
-        cache = state
+    else:  # mamba, mamba2
+        prefill = ssm_mod.mamba2_prefill if spec.kind == "mamba2" else ssm_mod.mamba_prefill
+        y, cache = prefill(cfg, lp["mixer"], h)
+        x = _residual(cfg, x, y)
     if enc_out is not None and "cross" in lp:
         hc = apply_norm(cfg, lp["ln_cross"], x)
-        x = x + attn.attention_fwd(cfg, lp["cross"], hc, spec, q_pos, kv_x=enc_out)
+        x = _residual(cfg, x, attn.attention_fwd(cfg, lp["cross"], hc, spec, q_pos, kv_x=enc_out))
         ek = dense(enc_out, lp["cross"]["wk"]).reshape(
             enc_out.shape[0], enc_out.shape[1], cfg.num_kv_heads, cfg.head_dim
         )
@@ -211,18 +221,19 @@ def layer_decode(cfg, spec, lp, x, t, cache):
         out, (kc, vc) = attn.attention_decode(
             cfg, lp["attn"], h, spec, (self_cache["k"], self_cache["v"]), t
         )
-        x = x + out
+        x = _residual(cfg, x, out)
         new_self = {"k": kc, "v": vc}
     else:
-        y, new_self = ssm_mod.mamba_decode(cfg, lp["mixer"], h, self_cache)
-        x = x + y
+        decode = ssm_mod.mamba2_decode if spec.kind == "mamba2" else ssm_mod.mamba_decode
+        y, new_self = decode(cfg, lp["mixer"], h, self_cache)
+        x = _residual(cfg, x, y)
     if has_cross:
         hc = apply_norm(cfg, lp["ln_cross"], x)
         out, _ = attn.attention_decode(
             cfg, lp["cross"], hc, spec, (cache["cross_k"], cache["cross_v"]), t,
             cross=True,
         )
-        x = x + out
+        x = _residual(cfg, x, out)
         new_cache = {"self": new_self, "cross_k": cache["cross_k"], "cross_v": cache["cross_v"]}
     else:
         new_cache = new_self
@@ -330,7 +341,12 @@ def _embed(cfg, params, tokens):
     # NOTE: no sharding constraint directly on the gather output -- the
     # SPMD partitioner mis-compiles gather+reshard (invalid dynamic-slice);
     # propagation from the batch-sharded indices is correct on its own.
-    return jnp.take(params["embed"], tokens, axis=0).astype(jnp.dtype(cfg.dtype))
+    return _scale_embed(cfg, jnp.take(params["embed"], tokens, axis=0).astype(jnp.dtype(cfg.dtype)))
+
+
+def _scale_embed(cfg, x):
+    m = cfg.embedding_multiplier
+    return x if m == 1.0 else x * jnp.asarray(m, x.dtype)
 
 
 def _unembed(cfg, params, x):
@@ -341,9 +357,10 @@ def _unembed(cfg, params, x):
     # bf16) beats all-reducing the (B, S, V/tp) f32 logits (~3 GB/micro) --
     # the constraint forces XLA into the weight-stationary plan.
     w = _constrain(w, (None, "tp"))
-    return jax.lax.dot_general(
+    logits = jax.lax.dot_general(
         x, w, (((x.ndim - 1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
+    return logits if cfg.logits_divisor == 1.0 else logits / cfg.logits_divisor
 
 
 def _run_encoder(cfg, params, enc_frames):
@@ -365,7 +382,7 @@ def forward(cfg: ModelConfig, params, batch) -> jax.Array:
     gather out of its accumulation scan (XLA's SPMD partitioner
     mis-compiles gathers inside while bodies at 256+ devices)."""
     if "x_embed" in batch:
-        x = batch["x_embed"].astype(jnp.dtype(cfg.dtype))
+        x = _scale_embed(cfg, batch["x_embed"].astype(jnp.dtype(cfg.dtype)))
         B, S = x.shape[:2]
     else:
         tokens = batch["tokens"]
@@ -487,6 +504,12 @@ def cache_skel(cfg: ModelConfig, batch: int, seq_len: int):
                 "conv": jax.ShapeDtypeStruct((batch, cfg.ssm_conv_width - 1, di), dt),
                 "ssm": jax.ShapeDtypeStruct((batch, di, cfg.ssm_state_dim), jnp.float32),
             }
+        if spec.kind == "mamba2":
+            H, P, _, N, conv = ssm_mod.mamba2_dims(cfg)
+            return {
+                "conv": jax.ShapeDtypeStruct((batch, cfg.ssm_conv_width - 1, conv), dt),
+                "ssm": jax.ShapeDtypeStruct((batch, H, P, N), jnp.float32),
+            }
         if spec.kind == "rwkv":
             d = cfg.d_model
             hs = cfg.rwkv_head_size
@@ -537,6 +560,8 @@ def cache_spec_skel(cfg: ModelConfig, b_ax, seq_ax, tp_ax):
                 "conv": P(None, b_ax, None, tp_ax),
                 "ssm": P(None, b_ax, tp_ax, None),
             }
+        if spec.kind == "mamba2":
+            return {"conv": P(None, b_ax, None, None), "ssm": P(None, b_ax, None, None, None)}
         if spec.kind == "rwkv":
             return {
                 "shift_t": P(None, b_ax, tp_ax),

@@ -5,8 +5,9 @@ Baseline data/tensor parallel step:
   * the per-step batch is split into ``num_microbatches`` accumulated with
     a lax.scan (f32 accumulator, sharded like the grads) -- this is what
     bounds activation memory at 4k x 256 global batch;
-  * the scanned block body is wrapped in jax.checkpoint (remat policy from
-    options);
+  * each layer of the scanned block body is wrapped in jax.checkpoint
+    (remat policy from options), so a block of several layers keeps one
+    layer's activations live in the backward, not all of them;
   * gradients within a pod reduce via GSPMD (XLA's allreduce);
   * gradients ACROSS pods reduce via the Hoplite chain collectives over
     the "pod" axis using a partial-manual shard_map -- the paper's
@@ -45,6 +46,11 @@ from repro.sharding.partitioning import ShardingOptions
 # compiler makes inside one carries its name as a whole segment of op_name
 # (the device trace's tf_op): forward, rematerialized and backward.
 SCOPES = ("attention", "mlp", "optimizer", "grad_sync")
+# Mamba-2's scopes, kept apart from SCOPES, whose four names the benchmark's
+# step split reads: ``ssm`` over each whole mixer (models/ssm._mamba2_mix,
+# a layer boundary like ``attention``) and ``ssd`` nested in it over the
+# chunked scan.
+SSM_SCOPES = ("ssm", "ssd")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -67,7 +73,7 @@ def _remat_wrap(fn, mode: str):
 
 
 def _loss_with_remat(cfg: ModelConfig, options: TrainOptions):
-    """train_loss with the stage-scan body rematerialized."""
+    """train_loss with each layer of the stage-scan body rematerialized."""
     if options.remat == "none":
         return lambda p, b: T.train_loss(cfg, p, b)
 
@@ -78,19 +84,16 @@ def _loss_with_remat(cfg: ModelConfig, options: TrainOptions):
         def body(carry, block_params):
             h, aux = carry
             h = T._constrain(h, ("batch", None, None))
+            for i, spec in enumerate(pattern):
 
-            def inner(h_, block_params_):
-                a_total = jnp.float32(0.0)
-                for i, spec in enumerate(pattern):
-                    h_, a = T.layer_fwd(
-                        cfg_, spec, block_params_[f"pos{i}"], h_, q_pos,
-                        positions_3d, enc_out, causal=causal,
-                    )
-                    a_total = a_total + a
-                return h_, a_total
+                def layer(h_, lp, spec=spec):
+                    h_, a = T.layer_fwd(cfg_, spec, lp, h_, q_pos, positions_3d, enc_out,
+                                        causal=causal)
+                    return h_, jnp.float32(0.0) + a
 
-            h, a = _remat_wrap(inner, options.remat)(h, block_params)
-            return (h, aux + a), None
+                h, a = _remat_wrap(layer, options.remat)(h, block_params[f"pos{i}"])
+                aux = aux + a
+            return (h, aux), None
 
         (x_out, aux), _ = jax.lax.scan(body, (x, jnp.float32(0.0)), stage_params)
         return x_out, aux

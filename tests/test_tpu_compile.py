@@ -11,8 +11,12 @@ import: only one process at a time may load the TPU library, and every
 test worker imports this file.
 """
 
+import os
+import sys
+
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 
@@ -20,6 +24,9 @@ from repro.core import collectives as C
 from repro.kernels import ops
 from repro.launch.mesh import auto_mesh
 from repro.models.attention import flash_ref
+from repro.models.ssm import ssd
+
+BENCH_TESTS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "bench_chip")
 
 MIB = 1 << 20
 GIB = 1 << 30
@@ -111,3 +118,60 @@ def test_hoplite_allreduce_compiles_on_four_chips(topo, allreduce):
         lambda a: allreduce(a, "x"), mesh=mesh, in_specs=P("x"), out_specs=P("x")
     )
     assert "collective-permute" in _hlo(f, x)
+
+
+def test_ssd_fwd_bwd_at_granite_width_compiles(one_chip):
+    """Granite 4.0-H's SSD on one row of 4096 positions: 64 heads of 64,
+    state 128, one group, chunks of 256; bf16 inputs, f32 decays."""
+    b, s, h, p, g, n = 1, 4096, 64, 64, 1, 128
+    x = _spec((b, s, h, p), jnp.bfloat16, one_chip)
+    dt = _spec((b, s, h), jnp.float32, one_chip)
+    A = _spec((h,), jnp.float32, one_chip)
+    B = _spec((b, s, g, n), jnp.bfloat16, one_chip)
+
+    def fwd_bwd(x, dt, A, B, C):
+        y, vjp = jax.vjp(lambda *a: ssd(*a, chunk=256)[0], x, dt, A, B, C)
+        return y, vjp(y)
+
+    compiled = jax.jit(fwd_bwd).lower(x, dt, A, B, B).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * GIB
+
+
+def test_ten_layer_pattern_keeps_one_layers_activations(topo):
+    """The tiny granite step (a pattern of ten layers in one block) with each
+    layer its own checkpoint keeps under half the bytes the block-level
+    checkpoint kept live."""
+    from jax.sharding import NamedSharding
+
+    sys.path.insert(0, BENCH_TESTS)
+    from granite_helpers import tiny_granite
+    from remat_helpers import block_remat_loss
+
+    from benchmarks.chip import harness
+    from repro.configs.base import ShapeSpec
+    from repro.data.pipeline import host_batch
+    from repro.launch.mesh import make_mesh
+    from repro.launch.train import build_step
+    from repro.train import step as TS
+
+    cell = tiny_granite(seq=240, batch=4)
+    cfg = harness.model_config(cell.config["program"])
+    shape = ShapeSpec(cell.name, 240, 4, "train")
+    mesh = make_mesh(np.array(topo.devices[:1]))
+    opts = TS.TrainOptions(remat="full", pod_sync="gspmd")
+    temp = {}
+    for block in (False, True):
+        orig = TS._loss_with_remat
+        if block:
+            TS._loss_with_remat = block_remat_loss
+        try:
+            with jax.set_mesh(mesh):
+                step, bspecs = build_step(cfg, shape, mesh, opts)
+                state = jax.tree.map(lambda a, sh: _spec(a.shape, a.dtype, sh),
+                                     TS.abstract_state(cfg), TS.state_shardings(cfg, mesh, opts))
+                batch = {k: _spec(v.shape, v.dtype, NamedSharding(mesh, bspecs[k]))
+                         for k, v in host_batch(cfg, shape, 0).items()}
+                temp[block] = step.lower(state, batch).compile().memory_analysis().temp_size_in_bytes
+        finally:
+            TS._loss_with_remat = orig
+    assert temp[False] < 0.5 * temp[True], temp
