@@ -12,6 +12,7 @@ inside ``shard_map``:
     and with C chunks costs (C + 2n - 3) steps of S/C bytes each
     ~= 2 S/B + 2 n (L + (S/C)/B)  -- bandwidth-competitive with ring
     allreduce while keeping the paper's reduce->broadcast structure.
+    Chunks are whole (rows, 128) tiles, so a step moves its chunk alone.
   * ``chain_reduce`` / ``chain_broadcast`` -- the unfused building blocks
     (Get/Reduce composition), also chunk-pipelined.
   * ``two_level_allreduce`` -- the paper's 2-D sqrt(n) chain: reduce within
@@ -101,15 +102,27 @@ def two_level_group_sizes(n: int, group_size: Optional[int] = None):
 # ---------------------------------------------------------------------------
 
 
+_LANES = 128
+
+
 def _to_chunks(x: jax.Array, num_chunks: int):
-    """Flatten and pad x to (num_chunks, chunk_elems)."""
+    """Flatten and pad x to (num_chunks, rows, _LANES).
+
+    ``rows`` is a multiple of the dtype's packed sublane count (8 rows of
+    32-bit, 16 of bf16, 32 of 8-bit), so a chunk is whole (rows, 128)
+    tiles and the chunk index is the untiled leading axis: slicing,
+    updating and ppermuting one chunk touches that chunk alone, in the
+    buffer's own layout.  The padding, fewer than ``unit * 128`` zeros
+    per chunk, is sliced off by ``_from_chunks``."""
     flat = x.reshape(-1)
     n = flat.shape[0]
-    chunk = -(-n // num_chunks)
-    pad = chunk * num_chunks - n
+    unit = max(8, 32 // x.dtype.itemsize)
+    rows = -(-n // (num_chunks * _LANES))
+    rows = -(-rows // unit) * unit
+    pad = num_chunks * rows * _LANES - n
     if pad:
         flat = jnp.pad(flat, (0, pad))
-    return flat.reshape(num_chunks, chunk), n
+    return flat.reshape(num_chunks, rows, _LANES), n
 
 
 def _from_chunks(chunks: jax.Array, orig_elems: int, shape, dtype):

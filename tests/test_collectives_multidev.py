@@ -128,3 +128,70 @@ def test_grad_sync_tree_methods():
         print("ok")
         """
     )
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize(
+    "fn", ["chain_allreduce", "chain_reduce", "chain_broadcast", "two_level_allreduce"]
+)
+def test_chunked_schedules_are_bitwise_their_fold(fn, dtype):
+    """Leaves whose size is no multiple of C x rows x 128, so every chunk
+    carries padding: each rank's result is bitwise the sum in the
+    schedule's own order (a chain folds rank 0 first)."""
+    run_subprocess(
+        f"""
+        import functools
+
+        def fold(parts):
+            acc = parts[0]
+            for p in parts[1:]:
+                acc = acc + p
+            return acc
+
+        g, m = C.two_level_group_sizes(8)
+        for shape, nc in (((1000,), 4), ((3, 1000), 5)):
+            xs = np.random.RandomState(7).randn(8, *shape).astype(jnp.{dtype})
+            cases = {{
+                "chain_allreduce": [(C.chain_allreduce, [fold(xs)] * 8)],
+                "chain_reduce": [(C.chain_reduce, [fold(xs[: i + 1]) for i in range(8)])],
+                "chain_broadcast": [
+                    (C.chain_broadcast, [xs[7]] * 8),
+                    (functools.partial(C.chain_broadcast, root="first"), [xs[0]] * 8),
+                ],
+                "two_level_allreduce": [
+                    (C.two_level_allreduce,
+                     [fold([fold(xs[q * g:(q + 1) * g]) for q in range(m)])] * 8),
+                ],
+            }}["{fn}"]
+            for op, want in cases:
+                f = jax.shard_map(lambda a: op(a[0], "x", nc)[None], mesh=mesh,
+                                  in_specs=P("x"), out_specs=P("x"))
+                with jax.set_mesh(mesh):
+                    got = np.asarray(jax.jit(f)(xs))
+                want = np.stack(want)
+                assert got.dtype == want.dtype, (got.dtype, want.dtype)
+                assert got.tobytes() == want.tobytes(), (shape, nc, np.abs(
+                    got.astype(np.float32) - want.astype(np.float32)).max())
+        print("ok")
+        """
+    )
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int8"])
+def test_chunk_layout_is_whole_tiles(dtype):
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core import collectives as C
+
+    x = jnp.asarray(np.arange(3000).reshape(3, 1000) % 101, dtype)
+    unit = 8 * 4 // jnp.dtype(dtype).itemsize
+    for nc in (1, 4, 5, 47):
+        chunks, orig = C._to_chunks(x, nc)
+        assert chunks.dtype == x.dtype and orig == x.size
+        assert chunks.shape[0] == nc and chunks.shape[2] == 128, chunks.shape
+        assert chunks.shape[1] % unit == 0, chunks.shape
+        assert chunks.size - x.size < nc * unit * 128
+        back = C._from_chunks(chunks, orig, x.shape, x.dtype)
+        assert back.shape == x.shape and back.dtype == x.dtype
+        assert np.asarray(back).tobytes() == np.asarray(x).tobytes()

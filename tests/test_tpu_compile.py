@@ -12,6 +12,7 @@ test worker imports this file.
 """
 
 import os
+import re
 import sys
 
 import jax
@@ -118,6 +119,26 @@ def test_hoplite_allreduce_compiles_on_four_chips(topo, allreduce):
         lambda a: allreduce(a, "x"), mesh=mesh, in_specs=P("x"), out_specs=P("x")
     )
     assert "collective-permute" in _hlo(f, x)
+
+
+def test_chain_allreduce_of_whisper_ffn_leaf_moves_whole_tiles(topo):
+    """One whisper FFN gradient leaf per chip, in the 47 chunks the chain
+    cell syncs it in: the schedule is the module's only loop (no chunk
+    relayout loops around it), and every ppermute sends one chunk as
+    whole (rows, 128) bf16 tiles."""
+    mesh = auto_mesh((4,), ("x",), topo.devices)
+    x = _spec((4 * 24, 4096, 1024), jnp.bfloat16, NamedSharding(mesh, P("x")))
+    f = jax.shard_map(
+        lambda a: C.chain_allreduce(a, "x", num_chunks=47),
+        mesh=mesh, in_specs=P("x"), out_specs=P("x"),
+    )
+    hlo = _hlo(f, x)
+    assert len(re.findall(r" while\(", hlo)) == 1
+    sends = re.findall(r"= \((\w+)\[([\d,]*)\].* collective-permute-start\(", hlo)
+    assert len(sends) == 2, sends
+    for dtype, dims in sends:
+        rows, lanes = map(int, dims.split(","))
+        assert dtype == "bf16" and lanes == 128 and rows % 16 == 0, (dtype, dims)
 
 
 def test_ssd_fwd_bwd_at_granite_width_compiles(one_chip):
