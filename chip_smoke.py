@@ -58,7 +58,7 @@ ALLREDUCES = {
     "two_level_allreduce": C.two_level_allreduce,
     "rs_ag_allreduce": C.rs_ag_allreduce,
 }
-POD_SYNCS = ("hoplite_chain", "hoplite_2d", "psum")
+POD_SYNCS = tuple(s for s in TS.POD_SYNCS if s != "gspmd")  # the syncs over a pod axis
 TRAIN_STEPS = 6  # two of them warm-up
 POD_STEPS = 4  # per sync method; three methods share the 1200 s budget
 LOSS_RTOL = 1e-2  # bf16 gradients summed in a different order

@@ -82,6 +82,7 @@ from repro.core.comm import (
 from repro.core.directory import ObjectDirectory, ReplicatedDirectory
 from repro.core.faults import FaultInjector, FaultPlan, FaultToleranceConfig
 from repro.core.planner import (
+    CollectiveConfig,
     LinkSpec,
     EC2_LINK,
     SPLICE_REJECT,
@@ -171,7 +172,7 @@ class AllreduceResult(str):
 
     The partial fold is the exact ``op``-fold of the participating
     contributions only -- it is NOT rescaled; see
-    ``collectives.partial_fold_scale`` for the unbiased-mean correction.
+    ``planner.partial_fold_scale`` for the unbiased-mean correction.
     """
 
     def __new__(cls, target_id: str, participants=(), dropped=(), mask=(),
@@ -236,16 +237,8 @@ class LocalCluster:
     ):
         # ``chunk_size=None`` autotunes per object via the Appendix-A cost
         # model (CollectiveConfig.chunks_for); an explicit value pins it.
-        self._explicit_chunk_size = chunk_size
         self.chunk_size = chunk_size if chunk_size is not None else DEFAULT_CHUNK_SIZE
-        self._autotune = None
-        if chunk_size is None:
-            try:  # collectives pulls in jax; core must work without it
-                from repro.core.collectives import CollectiveConfig
-
-                self._autotune = CollectiveConfig(link=link)
-            except Exception:  # noqa: BLE001 -- fall back to DEFAULT_CHUNK_SIZE
-                self._autotune = None
+        self._autotune = CollectiveConfig(link=link) if chunk_size is None else None
         self.link = link
         self.pace = pace
         self.store_capacity = store_capacity
@@ -360,9 +353,7 @@ class LocalCluster:
         else the Appendix-A autotuned count (more chunks for bigger
         objects / longer chains), rounded up to a 64-byte multiple so
         typed reduce windows stay element-aligned."""
-        if self._explicit_chunk_size is not None or self._autotune is None:
-            return self.chunk_size
-        if nbytes <= 0:
+        if self._autotune is None or nbytes <= 0:
             return self.chunk_size
         c = self._autotune.chunks_for(self.num_nodes, nbytes)
         chunk = -(-nbytes // c)

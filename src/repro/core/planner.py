@@ -16,18 +16,22 @@ Paper Appendix A:
 and each sqrt(n) chain recursively breaks down until m B L <= S, giving
 O(log log n) recursion depth.
 
-These functions are pure math shared by:
+It also holds the chunk plan of the chip's chain collectives: how many
+chunks an object is cut into (``autotune_num_chunks``) and which schedule
+moves it (``CollectiveConfig.choose``).
+
+These functions are pure math, free of JAX, shared by:
   * the discrete-event simulator (core/simulation.py),
   * the threaded in-process cluster (core/local.py),
-  * the TPU collective schedule builder (core/collectives.py), which feeds
-    ICI/DCN constants instead of TCP constants.
+  * the chip's shard_map programs (core/collectives.py) and the trainer
+    (train/step.py), which feed ICI/DCN constants instead of TCP constants.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import List, Sequence
+from typing import List, Optional, Sequence
 
 # ---------------------------------------------------------------------------
 # Hardware constants
@@ -76,6 +80,113 @@ def t_2d(n: int, link: LinkSpec, size: float) -> float:
 
 def predicted_reduce_time(n: int, link: LinkSpec, size: float) -> float:
     return min(t_1d(n, link, size), t_2d(n, link, size)) if n > 1 else 0.0
+
+
+# ---------------------------------------------------------------------------
+# Chunk plan of the chip's chain collectives (Appendix A)
+# ---------------------------------------------------------------------------
+
+# TPU analogue of the paper's 64 KB small-object threshold: below this a
+# plain psum beats any software-pipelined schedule (latency-bound regime).
+SMALL_TENSOR_BYTES = 256 * 1024
+
+# Autotuned chunk-count clamp: at least one chunk, at most this many
+# ppermute steps per leg, and never chunks smaller than MIN_CHUNK_BYTES
+# (tiny ppermute payloads are pure launch overhead).
+MAX_NUM_CHUNKS = 256
+MIN_CHUNK_BYTES = 1024
+
+
+def autotune_num_chunks(
+    axis_size: int,
+    nbytes: int,
+    link: LinkSpec = ICI_LINK,
+    step_overhead: float = 2e-6,
+) -> int:
+    """Appendix-A optimal chunk count for a fused chain schedule.
+
+    The fused chain allreduce runs ``C + 2n - 3`` ppermute steps of
+    ``S/C`` bytes, so with per-step latency ``L`` (link latency plus
+    software launch/sync overhead):
+
+        T(C) = (C + 2n - 3) * (L + S/(C*B))
+             = C*L + S/B + (2n-3)*L + (2n-3)*S/(C*B)
+
+    dT/dC = L - (2n-3)*S/(B*C^2) = 0  gives
+
+        C* = sqrt((2n-3) * S / (B * L))
+
+    -- more chunks for bigger objects (monotone nondecreasing in S,
+    unit-tested) and longer chains, fewer when per-step latency dominates.
+    Clamped to [1, MAX_NUM_CHUNKS] and to chunks of >= MIN_CHUNK_BYTES.
+    """
+    n = max(2, axis_size)
+    eff_latency = link.latency + step_overhead
+    c_opt = math.sqrt((2 * n - 3) * nbytes / (link.bandwidth * eff_latency))
+    c = int(max(1.0, c_opt))
+    c = min(c, MAX_NUM_CHUNKS, max(1, nbytes // MIN_CHUNK_BYTES))
+    return c
+
+
+def two_level_group_sizes(n: int, group_size: Optional[int] = None):
+    """(g, m): groups of size ``g``, ``m`` groups, for the 2-D sqrt(n)
+    chain -- g grows until it divides n (static perms need even groups).
+    The effective chain length of the 2-D schedule is ~``g + m``, which is
+    what chunk autotuning must use (not the 1-D length n)."""
+    g = group_size or max(2, math.isqrt(n))
+    while n % g != 0:
+        g += 1
+    return g, n // g
+
+
+@dataclasses.dataclass(frozen=True)
+class CollectiveConfig:
+    """Selection policy for one mesh axis (paper section 4.3 + App. A).
+
+    ``num_chunks=None`` (the default) derives the chunk count per
+    collective from the Appendix-A cost model -- ``autotune_num_chunks``
+    over (axis_size, nbytes, link, step_overhead).  An explicit integer
+    pins it (benchmark sweeps, regression repro)."""
+
+    link: LinkSpec = ICI_LINK
+    num_chunks: Optional[int] = None
+    small_bytes: int = SMALL_TENSOR_BYTES
+    # per-ppermute-step software overhead (launch + sync), seconds; this is
+    # the 'L' that actually matters for chunked schedules on TPU.
+    step_overhead: float = 2e-6
+
+    def effective_latency(self) -> float:
+        return self.link.latency + self.step_overhead
+
+    def chunks_for(self, axis_size: int, nbytes: int) -> int:
+        """Chunk count for a 1-D chain over ``axis_size`` ranks."""
+        if self.num_chunks is not None:
+            return self.num_chunks
+        return autotune_num_chunks(axis_size, nbytes, self.link, self.step_overhead)
+
+    def chunks_for_2d(self, axis_size: int, nbytes: int) -> int:
+        """Chunk count for the 2-D schedule, whose chain length is the
+        two-level g + m, not the 1-D axis_size."""
+        if self.num_chunks is not None:
+            return self.num_chunks
+        g, m = two_level_group_sizes(axis_size)
+        return autotune_num_chunks(g + m, nbytes, self.link, self.step_overhead)
+
+    def choose(self, axis_size: int, nbytes: int) -> str:
+        """The allreduce for one object: ``psum`` for small objects and
+        axes of two, else the paper's nBL > S rule between
+        ``hoplite_chain`` (1-D) and ``hoplite_2d`` (sqrt(n) chains)."""
+        if nbytes < self.small_bytes or axis_size <= 2:
+            return "psum"
+        eff = LinkSpec(self.link.bandwidth, self.effective_latency())
+        if use_two_dimensional(axis_size, eff, nbytes):
+            return "hoplite_2d"
+        return "hoplite_chain"
+
+
+ICI_CONFIG = CollectiveConfig(link=ICI_LINK)
+# The trainer's pod axis (ICI on a 2x2 host): modelled DCN constants, not calibrated (ROADMAP A2).
+POD_CONFIG = CollectiveConfig(link=DCN_LINK, step_overhead=10e-6)
 
 
 # ---------------------------------------------------------------------------
@@ -363,3 +474,24 @@ def bounded_time_participants(n: int, min_participants=None) -> int:
     [1, n]; k = n degenerates to the unbounded collective."""
     k = (n - 1) if min_participants is None else int(min_participants)
     return max(1, min(n, k))
+
+
+def partial_fold_scale(mask) -> float:
+    """Unbiased-mean correction for a bounded-time partial SUM fold.
+
+    ``LocalCluster.allreduce(..., deadline=, min_participants=)`` returns
+    the exact SUM of the *participating* contributions (``mask[i]`` True)
+    -- it never rescales the bytes it folds.  A data-parallel trainer
+    that divides the synchronized gradient by the WORLD size would bias
+    it low by ``kept/n``; multiply the partial sum by this factor
+    (``n / kept``) first so ``scaled_sum / n`` equals the mean over the
+    participants -- an unbiased estimate of the full mean when straggler
+    identity is independent of the gradient (the usual assumption; see
+    README "Fault injection and bounded-time collectives" for when it is
+    not).
+    """
+    mask = tuple(bool(m) for m in mask)
+    kept = sum(mask)
+    if kept == 0:
+        raise ValueError("partial_fold_scale: empty participation mask")
+    return len(mask) / kept

@@ -130,7 +130,6 @@ def build_cell(cfg, shape, mesh, pod_sync: str, variant: str = ""):
         num_microbatches=micro,
         remat="dots" if "rematdots" in variant else "full",
         pod_sync=pod_sync if "pod" in mesh.axis_names else "gspmd",
-        pod_compression="podcompress" in variant,
     )
     shopts = opts.sharding
     if shape.kind == "train":
@@ -184,8 +183,8 @@ def build_cell(cfg, shape, mesh, pod_sync: str, variant: str = ""):
 
 
 def apply_variant(variant: str) -> Dict[str, Any]:
-    """Perf-iteration knobs (EXPERIMENTS §Perf): comma-separated flags:
-    bf16partials | moedrop | rematdots | micro4 | micro8 | micro32 | podcompress."""
+    """Perf-iteration knobs, comma-separated flags:
+    bf16partials | moedrop | rematdots | micro4 | micro8 | micro32."""
     import jax.numpy as jnp
 
     from repro.models import common as C
@@ -198,7 +197,7 @@ def apply_variant(variant: str) -> Dict[str, Any]:
             C.set_matmul_partial_dtype(jnp.bfloat16)
         elif f == "moedrop":
             M.set_moe_mode("dropping")
-        elif f in ("rematdots", "micro4", "micro8", "micro32", "podcompress"):
+        elif f in ("rematdots", "micro4", "micro8", "micro32"):
             pass  # handled in build_cell via applied
         else:
             raise ValueError(f"unknown variant flag {f!r}")

@@ -42,8 +42,6 @@ from repro.launch.mesh import make_mesh
 from repro.sharding import partitioning
 from repro.train import step as TS
 
-POD_SYNCS = ("gspmd", "hoplite_chain", "hoplite_2d", "psum")
-
 
 def build_step(cfg: ModelConfig, shape: ShapeSpec, mesh, opts: TS.TrainOptions):
     """(jitted train step with the state donated, batch PartitionSpecs)."""
@@ -72,7 +70,7 @@ def main(argv=None):
     ap.add_argument("--microbatches", type=int, default=1)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=50)
-    ap.add_argument("--pod-sync", default="gspmd", choices=POD_SYNCS,
+    ap.add_argument("--pod-sync", default="gspmd", choices=TS.POD_SYNCS,
                     help="anything but gspmd puts a pod axis over all devices")
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)

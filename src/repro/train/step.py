@@ -1,4 +1,4 @@
-"""Train-step construction: pjit + remat + grad accumulation + Hoplite sync.
+"""Train-step construction: jit + remat + grad accumulation + Hoplite sync.
 
 Baseline data/tensor parallel step:
   * params FSDP(data) x TP(model), replicated over pod;
@@ -8,12 +8,13 @@ Baseline data/tensor parallel step:
   * each layer of the scanned block body is wrapped in jax.checkpoint
     (remat policy from options), so a block of several layers keeps one
     layer's activations live in the backward, not all of them;
-  * gradients within a pod reduce via GSPMD (XLA's allreduce);
-  * gradients ACROSS pods reduce via the Hoplite chain collectives over
-    the "pod" axis using a partial-manual shard_map -- the paper's
-    schedule runs on exactly the axis where link latency/bandwidth makes
-    scheduling matter (DCN), optionally int8-compressed with error
-    feedback.
+  * gradients over the data/model axes reduce via GSPMD (XLA's allreduce);
+  * with a "pod" axis in the mesh and ``pod_sync`` other than ``gspmd``, the
+    step runs manual over "pod" in a partial-manual shard_map and
+    ``collectives.grad_sync`` reduces the gradients across it: the Hoplite
+    chain (``hoplite_chain``), the 2-D chain (``hoplite_2d``) or XLA's psum
+    (``psum``).  On one host the pod axis is the chips' ICI, and the chains
+    are cut into the chunks ``planner.POD_CONFIG`` plans.
 
 The returned step has signature  (state, batch) -> (state, metrics)  and
 is ready for jit/lower with the shardings attached.
@@ -22,16 +23,14 @@ is ready for jit/lower with the shardings attached.
 from __future__ import annotations
 
 import dataclasses
-import functools
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Dict, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeSpec
-from repro.core import collectives
+from repro.core import collectives, planner
 from repro.models import transformer as T
 from repro.models.common import abstract_params, init_params
 from repro.optim import adamw
@@ -51,14 +50,16 @@ SCOPES = ("attention", "mlp", "optimizer", "grad_sync")
 # a layer boundary like ``attention``) and ``ssd`` nested in it over the
 # chunked scan.
 SSM_SCOPES = ("ssm", "ssd")
+# How the gradients are reduced over the mesh's "pod" axis: ``gspmd`` leaves
+# it to XLA; each other value is the ``collectives.grad_sync`` method.
+POD_SYNCS = ("gspmd", "hoplite_chain", "hoplite_2d", "psum")
 
 
 @dataclasses.dataclass(frozen=True)
 class TrainOptions:
     num_microbatches: int = 1
     remat: str = "full"  # none | full | dots
-    pod_sync: str = "hoplite_chain"  # gspmd | hoplite_chain | hoplite_2d | psum
-    pod_compression: bool = False  # int8 + error feedback on the pod axis
+    pod_sync: str = "hoplite_chain"  # one of POD_SYNCS
     adamw: AdamWConfig = AdamWConfig()
     sharding: ShardingOptions = ShardingOptions()
 
@@ -121,32 +122,6 @@ def _split_micro(batch: Dict[str, jax.Array], n: int):
     return {k: split(k, v) for k, v in batch.items()}
 
 
-def _pod_sync_fn(options: TrainOptions):
-    method = {
-        "hoplite_chain": "chain",
-        "hoplite_2d": "chain2d",
-        "psum": "psum",
-    }[options.pod_sync]
-
-    def sync(grads):
-        if options.pod_compression:
-            from repro.optim import compression
-
-            def raw_sync(g):
-                return collectives.grad_sync(
-                    g, "pod", method=method, config=collectives.DCN_CONFIG
-                )
-
-            # residuals threaded through state by the caller; here we use
-            # stateless compress (residuals handled in train_step carry)
-            return raw_sync(jax.tree_util.tree_map(compression.compress_decompress, grads))
-        return collectives.grad_sync(
-            grads, "pod", method=method, config=collectives.DCN_CONFIG
-        )
-
-    return sync
-
-
 def make_train_step(cfg: ModelConfig, mesh: Mesh, shape: ShapeSpec, options: TrainOptions = TrainOptions()):
     """Build (train_step, state_specs, batch_specs).
 
@@ -206,7 +181,7 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, shape: ShapeSpec, options: Tra
     def step_core(state, batch):
         loss, grads = grads_of(state["params"], batch)
         if use_hoplite_pod:
-            grads = _pod_sync_fn(options)(grads)
+            grads = collectives.grad_sync(grads, "pod", options.pod_sync, planner.POD_CONFIG)
             # scalar: the small-object fast path (psum), per the dispatcher
             loss = jax.lax.psum(loss, "pod") / mesh.shape["pod"]
         new_params, new_opt, metrics = adamw.adamw_update(
@@ -217,18 +192,11 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, shape: ShapeSpec, options: Tra
         return new_state, metrics
 
     if use_hoplite_pod:
-        # manual over 'pod' (Hoplite chain on DCN); GSPMD handles data/model.
+        # manual over 'pod' (the chips' ICI on one host), where grad_sync
+        # runs; GSPMD handles data/model.
         skel = T.model_skel(cfg)
         pspecs = partitioning.param_specs(cfg, skel, mesh, options.sharding)
         bspecs = partitioning.batch_specs(cfg, mesh, shape, options.sharding)
-
-        def strip_pod(spec: P):
-            return P(*[
-                (tuple(a for a in e if a != "pod") or None)
-                if isinstance(e, tuple)
-                else (None if e == "pod" else e)
-                for e in spec
-            ])
 
         # state replicated over pod; batch sharded over pod on dim 0 (dim 1
         # for positions_3d)

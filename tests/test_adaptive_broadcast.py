@@ -240,8 +240,8 @@ def test_first_location_all_candidates_dead_raises_promptly():
 
 
 def test_chunk_autotune_default_and_override():
-    """LocalCluster chunk sizing rides CollectiveConfig.chunks_for unless
-    explicitly overridden."""
+    """LocalCluster chunk sizing rides planner.CollectiveConfig.chunks_for
+    (imported directly, no fallback) unless explicitly overridden."""
     auto = LocalCluster(8)
     big, small = 4 << 20, 64 << 10
     cb, cs = auto.chunk_size_for(big), auto.chunk_size_for(small)

@@ -1,17 +1,18 @@
-"""Chunk-count autotuning (Appendix-A cost model, core/collectives.py)."""
+"""Chunk-count autotuning (Appendix-A cost model, core/planner.py)."""
 
 import numpy as np
 import pytest
 
-from repro.core.collectives import (
-    DCN_CONFIG,
+from repro.core.planner import (
+    DCN_LINK,
     ICI_CONFIG,
+    ICI_LINK,
     MAX_NUM_CHUNKS,
     MIN_CHUNK_BYTES,
+    POD_CONFIG,
     CollectiveConfig,
     autotune_num_chunks,
 )
-from repro.core.planner import DCN_LINK, ICI_LINK
 
 
 def test_chosen_c_is_monotone_in_size():
@@ -65,6 +66,7 @@ def test_explicit_override_kept():
 
 
 def test_dcn_uses_fewer_chunks_than_ici_for_same_shape():
-    """Higher per-step latency (DCN) pushes toward fewer, larger chunks."""
+    """Higher per-step latency (the DCN constants of the trainer's pod axis)
+    pushes toward fewer, larger chunks."""
     S = 64 << 20
-    assert DCN_CONFIG.chunks_for(16, S) <= ICI_CONFIG.chunks_for(16, S)
+    assert POD_CONFIG.chunks_for(16, S) <= ICI_CONFIG.chunks_for(16, S)

@@ -117,7 +117,7 @@ def test_grad_sync_tree_methods():
     run_subprocess(
         """
         tree = {"a": x, "b": x[:, :17] * 2}
-        for method in ("psum", "hoplite", "chain", "rs_ag"):
+        for method in ("psum", "hoplite_chain", "hoplite_2d"):
             def sync(t):
                 return C.grad_sync(t, "x", method=method, mean=True)
             g = jax.shard_map(sync, mesh=mesh, in_specs=({"a": P("x"), "b": P("x")},),
@@ -125,6 +125,16 @@ def test_grad_sync_tree_methods():
             with jax.set_mesh(mesh):
                 out = jax.jit(g)(tree)
             np.testing.assert_allclose(np.asarray(out["a"]), want / 8, rtol=1e-5)
+        for method in ("hoplite", "chain", "chain2d", "rs_ag"):  # not grad_sync methods
+            g = jax.shard_map(lambda t: C.grad_sync(t, "x", method), mesh=mesh,
+                              in_specs=(P("x"),), out_specs=P("x"))
+            try:
+                with jax.set_mesh(mesh):
+                    jax.jit(g)(x)
+            except ValueError as e:
+                assert method in str(e), e
+            else:
+                raise AssertionError(f"grad_sync accepted {method!r}")
         print("ok")
         """
     )

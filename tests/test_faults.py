@@ -204,7 +204,7 @@ def test_bounded_allreduce_deadline_none_folds_at_quorum():
 
 
 def test_partial_fold_scale():
-    from repro.core.collectives import partial_fold_scale
+    from repro.core.planner import partial_fold_scale
 
     assert partial_fold_scale((True, True, True, False)) == pytest.approx(4 / 3)
     assert partial_fold_scale((True,) * 8) == 1.0
